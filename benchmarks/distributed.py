@@ -1,7 +1,7 @@
 """Sharded search benchmark: QPS vs shard count + merge-collective bytes.
 
 Runs the SAME IndexCore search (`core_search` under shard_map) over 1, 2,
-4, and 8 row shards of fake host devices and measures
+4, ... row shards, as many as the process has devices, and measures
 
   * end-to-end search wall time / QPS per shard count (quantized
     packed-code path, exact rerank on-shard),
@@ -13,22 +13,18 @@ Runs the SAME IndexCore search (`core_search` under shard_map) over 1, 2,
   * a fused-Pallas-kernel-path cell at the max shard count (parity +
     no-tombstone-leak check after a delete wave).
 
-Standalone (the device-count flag must precede jax init):
+Runs in the calling process over its real devices (one process holds a
+chip, so no child may need it):
 
-    PYTHONPATH=src python -m benchmarks.distributed [--fast]
+    PYTHONPATH=src:. python -m benchmarks.run --only distributed [--fast]
 
-`benchmarks/run.py --only distributed` spawns it as a subprocess for the
-same reason. Emits BENCH_distributed.json.
+A CPU rehearsal of the multi-shard cells gives the process fake devices:
+XLA_FLAGS=--xla_force_host_platform_device_count=8. Emits
+BENCH_distributed.json.
 """
 
 from __future__ import annotations
 
-import os
-
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                           + os.environ.get("XLA_FLAGS", ""))
-
-import argparse
 import json
 import time
 
@@ -38,7 +34,13 @@ DIMS = 64
 K = 10
 BEAM = 32
 N_QUERIES = 256
-SHARD_COUNTS = (1, 2, 4, 8)
+
+
+def _shard_counts() -> tuple[int, ...]:
+    """Powers of two up to the process's device count."""
+    import jax
+    n_dev = len(jax.devices())
+    return tuple(s for s in (1, 2, 4, 8) if s <= n_dev)
 
 
 def _make_mesh(n_shards: int):
@@ -60,7 +62,7 @@ def run(csv, n: int | None = None,
 
     records: list[dict] = []
     idx = None
-    for s in SHARD_COUNTS:
+    for s in _shard_counts():
         mesh = _make_mesh(s)
         cap = -(-int(n * 1.25) // s)
         cap += (-cap) % 8
@@ -116,8 +118,9 @@ def run(csv, n: int | None = None,
                     "recall_at_10_after_deletes": round(rec_k, 4),
                     "tombstone_leaks": leaked})
 
-    # elastic reshard: a checkpoint saved at 4 shards (tombstones and all)
-    # restores at 1, 2, and 8 — recall at EQUAL TOTAL SEARCH BUDGET
+    # elastic reshard: a checkpoint saved at the max shard count
+    # (tombstones and all) restores at the others — recall at EQUAL TOTAL
+    # SEARCH BUDGET
     # (S' shards x TOTAL_BEAM/S' beam each) must hold, and the fused
     # kernel path must leak zero tombstones after the move
     reshard = _run_reshard(csv, data, queries, rng, n)
@@ -138,24 +141,26 @@ def _run_reshard(csv, data, queries, rng, n: int) -> dict:
     from repro.core.distributed import ShardedJasperIndex
     from benchmarks.common import BENCH_PARAMS
 
-    mesh4 = _make_mesh(4)
-    cap = -(-int(n * 1.25) // 4)
+    counts = _shard_counts()
+    src = counts[-1]
+    mesh_src = _make_mesh(src)
+    cap = -(-int(n * 1.25) // src)
     cap += (-cap) % 8
-    idx4 = ShardedJasperIndex(mesh4, DIMS, capacity_per_shard=cap,
-                              construction=BENCH_PARAMS,
-                              quantization="rabitq", bits=4)
-    idx4.build(data)
-    per = n // 4
+    idx_src = ShardedJasperIndex(mesh_src, DIMS, capacity_per_shard=cap,
+                                 construction=BENCH_PARAMS,
+                                 quantization="rabitq", bits=4)
+    idx_src.build(data)
+    per = n // src
     dead = rng.choice(n, max(64, n // 16), replace=False)
-    gids = (dead // per) * idx4.id_stride + dead % per
-    idx4.delete(gids)
+    gids = (dead // per) * idx_src.id_stride + dead % per
+    idx_src.delete(gids)
     path = f"{tempfile.mkdtemp()}/ck"
-    idx4.save(path)
-    total_beam = 4 * BEAM
-    base = idx4.recall(queries, K, beam_width=total_beam // 4,
-                       quantized=True)
+    idx_src.save(path)
+    total_beam = src * BEAM
+    base = idx_src.recall(queries, K, beam_width=total_beam // src,
+                          quantized=True)
     restores = []
-    for s in (1, 2, 8):
+    for s in [c for c in counts if c != src] or [src]:
         t0 = _time.perf_counter()
         idx_r = ShardedJasperIndex.load(_make_mesh(s), path, n_shards=s)
         load_s = _time.perf_counter() - t0
@@ -167,32 +172,18 @@ def _run_reshard(csv, data, queries, rng, n: int) -> dict:
         ret = ids_np[ids_np >= 0]
         leaks = int(idx_r.tombstoned(ret).sum())
         tr = idx_r.reshard_translation
-        csv.add(f"distributed/reshard_4to{s}", load_s * 1e6,
+        csv.add(f"distributed/reshard_{src}to{s}", load_s * 1e6,
                 f"recall={rec:.3f} d={rec - base:+.3f} leaks={leaks}")
         restores.append({
             "restore_shards": s, "restore_s": round(load_s, 2),
             "beam_width_per_shard": bw,
             "recall_at_10": round(rec, 4),
-            "recall_delta_vs_4shard": round(rec - base, 4),
+            "recall_delta_vs_source": round(rec - base, 4),
             "kernel_tombstone_leaks": leaks,
             "ids_translated": len(tr),
         })
-    return {"from_shards": 4, "n_deleted": int(dead.size),
+    return {"from_shards": src, "n_deleted": int(dead.size),
             "total_beam": total_beam,
             "baseline_recall_at_10": round(base, 4),
             "restores": restores}
 
-
-def main() -> None:
-    ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--fast", action="store_true")
-    ap.add_argument("--out-json", default="BENCH_distributed.json")
-    args = ap.parse_args()
-    from benchmarks.common import Csv
-    csv = Csv()
-    csv.header()
-    run(csv, n=2048 if args.fast else None, out_json=args.out_json)
-
-
-if __name__ == "__main__":
-    main()

@@ -13,8 +13,8 @@ import traceback
 
 from benchmarks import (
     construction,
+    distributed,
     incremental,
-    loads,
     quantization,
     queries,
     roofline_anns,
@@ -24,6 +24,7 @@ from benchmarks import (
     updates,
 )
 from benchmarks.common import Csv
+from repro.launch.compile_cache import enable_compile_cache
 
 SECTIONS = {
     # paper Table 4
@@ -43,8 +44,6 @@ SECTIONS = {
     # paper Fig 12
     "quantization": lambda csv, fast: quantization.run(
         csv, n=3000 if fast else None),
-    # paper Table 5 / Fig 4
-    "loads": lambda csv, fast: loads.run(csv),
     # paper Figs 10-11
     "tiles": lambda csv, fast: tiles.run(csv),
     # per-hop frontier merge A/B (partial top-L vs full sort vs kernel)
@@ -61,21 +60,11 @@ SECTIONS = {
     # code-only floor (emits BENCH_tiering.json)
     "tiering": lambda csv, fast: tiering.run(
         csv, n=2000 if fast else None),
-    # sharded search: QPS vs shard count + merge-collective bytes.
-    # Subprocess: the multi-device XLA flag must precede jax init, and by
-    # the time run.py gets here jax is already initialized single-device.
-    "distributed": lambda csv, fast: _run_distributed_subprocess(fast),
+    # sharded search over this process's devices: QPS vs shard count +
+    # merge-collective bytes (in-process: one process holds the chips)
+    "distributed": lambda csv, fast: distributed.run(
+        csv, n=2048 if fast else None),
 }
-
-
-def _run_distributed_subprocess(fast: bool) -> None:
-    import subprocess
-    cmd = [sys.executable, "-m", "benchmarks.distributed"]
-    if fast:
-        cmd.append("--fast")
-    res = subprocess.run(cmd)
-    if res.returncode:
-        raise RuntimeError(f"benchmarks.distributed exited {res.returncode}")
 
 
 def main() -> None:
@@ -85,6 +74,7 @@ def main() -> None:
     ap.add_argument("--only", action="append", default=None,
                     choices=sorted(SECTIONS))
     args = ap.parse_args()
+    enable_compile_cache()
 
     csv = Csv()
     csv.header()

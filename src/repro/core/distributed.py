@@ -46,9 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.beam_search import SearchTelemetry
-from repro.core.construction import ConstructionParams
+from repro.core.construction import MAX_BATCH, ConstructionParams
 from repro.core.index_core import (
     IndexCore,
     attach_quantizer,
@@ -165,7 +164,7 @@ def _shard_index(row_axes, axis_sizes) -> Array:
     """Linear shard index of this device along the row axes.
 
     axis_sizes: static {axis: size} (mesh.shape) — axis extents are mesh
-    constants, so no in-graph axis_size query (0.4.x compat) is needed."""
+    constants, so no in-graph axis_size query is needed."""
     idx = jnp.int32(0)
     mult = 1
     for ax in reversed(row_axes):
@@ -258,7 +257,7 @@ def sharded_search_fn(mesh: Mesh, shard_spec: ShardSpec,
     if filtered:
         in_specs = in_specs + (P(),)
         in_shardings = in_shardings + (NamedSharding(mesh, P()),)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_search, mesh=mesh,
         in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return jax.jit(fn, in_shardings=in_shardings)
@@ -308,7 +307,7 @@ def sharded_traversal_fn(mesh: Mesh, shard_spec: ShardSpec,
     if filtered:
         in_specs = in_specs + (P(),)
         in_shardings = in_shardings + (NamedSharding(mesh, P()),)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_traverse, mesh=mesh,
         in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return jax.jit(fn, in_shardings=in_shardings)
@@ -326,7 +325,7 @@ def sharded_insert_fn(mesh: Mesh, spec: ShardSpec, template: IndexCore, *,
         return _restack(core)
 
     specs = core_partition_specs(template, spec)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_insert, mesh=mesh,
         in_specs=(specs, P(spec.row_axes, None), P(spec.row_axes, None, None)),
         out_specs=specs, check_vma=False)
@@ -341,7 +340,7 @@ def sharded_bootstrap_fn(mesh: Mesh, spec: ShardSpec, template: IndexCore, *,
         return _restack(core)
 
     specs = core_partition_specs(template, spec)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_boot, mesh=mesh,
         in_specs=(specs, P(spec.row_axes, None, None)),
         out_specs=specs, check_vma=False)
@@ -357,7 +356,7 @@ def sharded_delete_fn(mesh: Mesh, spec: ShardSpec, template: IndexCore):
         return _restack(core), n_new[None]
 
     specs = core_partition_specs(template, spec)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_delete, mesh=mesh,
         in_specs=(specs, P(spec.row_axes, None)),
         out_specs=(specs, P(spec.row_axes)), check_vma=False)
@@ -719,11 +718,13 @@ class ShardedJasperIndex(SearchSurface):
         boot = self._fn("boot", n0=n0)
         self.core = boot(self.core, dealt[:, :n0])
 
-        # prefix-doubling schedule, every rung inserted into EVERY shard
+        # prefix-doubling schedule up to MAX_BATCH rows (device memory),
+        # every rung inserted into EVERY shard
         inserted = n0
         while inserted < per:
             remaining = per - inserted
-            b = min(max(256, 1 << (inserted.bit_length() - 1)), remaining)
+            b = min(max(256, 1 << (inserted.bit_length() - 1)), MAX_BATCH,
+                    remaining)
             if b != remaining:
                 b = 1 << (b.bit_length() - 1)
             ids = jnp.tile(jnp.arange(inserted, inserted + b,

@@ -295,12 +295,14 @@ def consolidate(vectors: Array, graph: VamanaGraph, state: MutationState, *,
 
     Two repair modes (A/B'd in benchmarks/updates.py):
 
-    refine=True (default) — snapshot RE-LINK: every touched row re-runs the
+    refine=True (default) — RE-LINK: every touched row re-runs the
     insertion pipeline against the tombstoned graph (beam search traverses
     THROUGH deleted rows — connectivity — while the live mask keeps them
     out of every pruned edge list), via `batch_insert_at(already_inserted=
-    True)`. Globally good candidates, post-churn recall at fresh-build
-    level, and ~2x cheaper than a one-hop repair + refine stack.
+    True)`, in slabs of at most `construction.MAX_BATCH` rows, each slab
+    against the graph the slabs before it left. Globally good candidates,
+    post-churn recall at fresh-build level, and ~2x cheaper than a one-hop
+    repair + refine stack.
 
     refine=False — LOCAL one-hop repair (FreshDiskANN's rule): each touched
     row re-prunes over (its live neighbors ∪ its deleted neighbors' live
@@ -326,17 +328,25 @@ def consolidate(vectors: Array, graph: VamanaGraph, state: MutationState, *,
 
     adj = graph.adjacency
     if refine and touched.size:
-        from repro.core.construction import batch_insert_at  # lazy: no cycle
-        # pad to a power-of-two rung (one executable per rung) by repeating
-        # a real id — a duplicate re-link is idempotent, while -1 padding
-        # would corrupt the adjacency scatter
-        rung = 1 << max(0, int(touched.size - 1).bit_length())
-        t_pad = np.concatenate([touched, np.full((rung - touched.size,),
-                                                 touched[0], np.int64)])
-        graph = batch_insert_at(vectors, graph,
-                                jnp.asarray(t_pad, jnp.int32), params=params,
-                                already_inserted=True, vec_sqnorm=vec_sqnorm,
-                                tombstone_bits=state.tombstone_bits)
+        from repro.core.construction import (  # lazy: no cycle
+            MAX_BATCH, batch_insert_at)
+        # re-link in slabs of at most MAX_BATCH rows (one slab over a
+        # large graph's touched set would not fit device memory). Each slab
+        # pads to a power-of-two rung by repeating a real id — a duplicate
+        # re-link is idempotent, while -1 padding would corrupt the
+        # adjacency scatter; past one slab every slab takes the full rung,
+        # so a consolidation compiles one executable
+        rung = (MAX_BATCH if touched.size > MAX_BATCH
+                else 1 << max(0, int(touched.size - 1).bit_length()))
+        for s in range(0, touched.size, MAX_BATCH):
+            slab = touched[s:s + MAX_BATCH]
+            t_pad = np.concatenate([slab, np.full((rung - slab.size,),
+                                                  slab[0], np.int64)])
+            graph = batch_insert_at(vectors, graph,
+                                    jnp.asarray(t_pad, jnp.int32),
+                                    params=params, already_inserted=True,
+                                    vec_sqnorm=vec_sqnorm,
+                                    tombstone_bits=state.tombstone_bits)
         adj = graph.adjacency
     elif touched.size:
         # local repair in fixed-shape slabs; chunk bounds the

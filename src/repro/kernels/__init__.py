@@ -2,11 +2,10 @@
 
 Four kernels, each with a pure-jnp oracle (ref.py) and a jit'd public
 wrapper (ops.py); validated against the oracle across shape/dtype sweeps in
-interpret mode (this container is CPU-only; TPU is the compile target):
+interpret mode on CPU; TPU is the compile target (kernels/backend.py):
 
-  distance/    tiled pairwise L2 on the MXU + the two *gather* variants that
-               mirror the paper's Table 5 load-strategy study (tiled row-DMA
-               vs chunked bulk loads)
+  distance/    tiled pairwise L2 on the MXU + the bulk-gather (chunked load)
+               candidate scorer
   rabitq_dot/  fused bit-unpack + estimator inner product for RaBitQ codes,
                incl. the search-step variant with fused invalid-id masking
   topk/        small-k frontier top-k via iterative min-extraction

@@ -1,19 +1,13 @@
-"""Pallas TPU distance kernels (paper §4.1/§4.2 + Table 5).
+"""Pallas TPU distance kernels (paper §4.1/§4.2).
 
-Three kernels:
+Two kernels:
 
   _pairwise_kernel      classic (nQ, nC, nD)-tiled MXU matmul with a fused
                         squared-L2 epilogue — brute force / bootstrap /
                         rerank path. The BlockSpec tiling keeps the working
                         set in VMEM with MXU-aligned (multiple-of-128) dims.
 
-  _gather_tiled_kernel  "tiled" load strategy (paper Fig 4, left): grid step
-                        (q, k) DMAs ONE candidate row HBM->VMEM via a
-                        scalar-prefetched index map, then a VPU row dot.
-                        One outstanding row per step = the latency-exposed
-                        baseline the paper measures against.
-
-  _gather_chunked_kernel "chunked" strategy (paper Fig 4, right): candidates
+  _gather_chunked_kernel bulk-load strategy (paper Fig 4, right): candidates
                         are pre-gathered into a contiguous (Q, K, D) buffer
                         so each grid step issues ONE bulk DMA of a whole
                         (TQ, K, D) tile and the dot runs batched on the MXU.
@@ -31,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 Array = jax.Array
 
@@ -75,46 +67,10 @@ def pairwise_l2_pallas(q: Array, x: Array, qsq: Array, xsq: Array, *,
         out_specs=pl.BlockSpec((block_q, block_c), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, cn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, block_c), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, x, qsq.reshape(-1, 1), xsq.reshape(-1, 1))
-
-
-# ------------------------------------------------------------ gather: tiled
-def _gather_tiled_kernel(ids_ref, q_ref, qsq_ref, row_ref, rsq_ref, o_ref):
-    dot = jnp.sum(q_ref[0, :] * row_ref[0, :])
-    o_ref[0, 0] = jnp.maximum(qsq_ref[0, 0] - 2.0 * dot + rsq_ref[0, 0], 0.0)
-
-
-def gather_l2_tiled_pallas(q: Array, db: Array, db_sq: Array, ids: Array,
-                           *, interpret: bool = False) -> Array:
-    """One-row-per-step gather distances ("tiled" strategy).
-
-    ids must be pre-clipped to [0, N); masking of invalid ids happens in
-    ops.py. Grid = (Q, K): each step's BlockSpec index map dereferences the
-    scalar-prefetched id to pick WHICH db row block to DMA.
-    """
-    qn, d = q.shape
-    k = ids.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(qn, k),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, ids_ref: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, ids_ref: (i, 0)),
-            pl.BlockSpec((1, d), lambda i, j, ids_ref: (ids_ref[i, j], 0)),
-            pl.BlockSpec((1, 1), lambda i, j, ids_ref: (ids_ref[i, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j, ids_ref: (i, j)),
-    )
-    qsq = jnp.sum(q.astype(jnp.float32) ** 2, axis=-1, keepdims=True)
-    return pl.pallas_call(
-        _gather_tiled_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qn, k), jnp.float32),
-        interpret=interpret,
-    )(ids, q, qsq, db, db_sq.reshape(-1, 1))
 
 
 # ---------------------------------------------------------- gather: chunked
@@ -149,7 +105,7 @@ def gather_l2_chunked_pallas(q: Array, cand: Array, cand_sq: Array, *,
         ],
         out_specs=pl.BlockSpec((block_q, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((qn, k), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q, qsq, cand, cand_sq)

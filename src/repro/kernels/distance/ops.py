@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the distance kernels (padding + masking).
 
-`interpret` defaults to auto: real Mosaic lowering on TPU, interpreter on
-CPU (this container). All wrappers mask invalid/padded entries to +inf so
-callers can feed beam-search id buffers directly.
+`interpret=None` resolves through `kernels.backend.resolve_interpret`:
+Mosaic on TPU, the interpreter on CPU. All wrappers mask invalid/padded
+entries to +inf so callers can feed beam-search id buffers directly.
 """
 
 from __future__ import annotations
@@ -14,17 +14,13 @@ import jax.numpy as jnp
 
 from repro.kernels.distance.distance_kernel import (
     gather_l2_chunked_pallas,
-    gather_l2_tiled_pallas,
     pairwise_l2_pallas,
 )
+from repro.kernels.backend import resolve_interpret
 
 Array = jax.Array
 
 _INF = float("inf")
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: Array, mult: int, axis: int, value=0.0) -> Array:
@@ -40,8 +36,7 @@ def _pad_to(x: Array, mult: int, axis: int, value=0.0) -> Array:
 def pairwise_l2(q: Array, x: Array, *, block_q: int = 128, block_c: int = 128,
                 block_d: int = 512, interpret: bool | None = None) -> Array:
     """(Q, D) x (C, D) -> (Q, C) squared L2 via the tiled MXU kernel."""
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     qn, d = q.shape
     cn = x.shape[0]
     block_d = min(block_d, max(128, d))
@@ -57,19 +52,6 @@ def pairwise_l2(q: Array, x: Array, *, block_q: int = 128, block_c: int = 128,
     return out[:qn, :cn]
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def gather_l2_tiled(q: Array, db: Array, db_sq: Array, ids: Array, *,
-                    interpret: bool | None = None) -> Array:
-    """Row-at-a-time gather distances; invalid ids -> +inf."""
-    if interpret is None:
-        interpret = _auto_interpret()
-    q = _pad_to(q.astype(jnp.float32), 128, 1)
-    db = _pad_to(db.astype(jnp.float32), 128, 1)
-    safe = jnp.maximum(ids, 0).astype(jnp.int32)
-    out = gather_l2_tiled_pallas(q, db, db_sq, safe, interpret=interpret)
-    return jnp.where(ids >= 0, out, _INF)
-
-
 @partial(jax.jit, static_argnames=("block_q", "interpret"))
 def gather_l2_chunked(q: Array, db: Array, db_sq: Array, ids: Array, *,
                       block_q: int = 8, interpret: bool | None = None) -> Array:
@@ -78,8 +60,7 @@ def gather_l2_chunked(q: Array, db: Array, db_sq: Array, ids: Array, *,
     The XLA gather materializes the contiguous (Q, K, D) candidate buffer
     (the "chunk"); the kernel then streams it in (TQ, K, D) tiles.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     qn = q.shape[0]
     q = _pad_to(q.astype(jnp.float32), 128, 1)
     db = _pad_to(db.astype(jnp.float32), 128, 1)
@@ -96,7 +77,6 @@ def gather_l2_chunked(q: Array, db: Array, db_sq: Array, ids: Array, *,
 
 def make_kernel_scorer(vectors: Array, queries: Array, n_valid: Array,
                        vec_sqnorm: Array | None = None, *,
-                       strategy: str = "chunked",
                        tombstone_bits: Array | None = None,
                        labels: Array | None = None,
                        filter_bytes: Array | None = None,
@@ -115,7 +95,6 @@ def make_kernel_scorer(vectors: Array, queries: Array, n_valid: Array,
     v = vectors
     if vec_sqnorm is None:
         vec_sqnorm = jnp.sum(v.astype(jnp.float32) ** 2, axis=-1)
-    fn = gather_l2_chunked if strategy == "chunked" else gather_l2_tiled
 
     def score(ids: Array) -> Array:
         in_range = (ids >= 0) & (ids < n_valid)
@@ -126,7 +105,8 @@ def make_kernel_scorer(vectors: Array, queries: Array, n_valid: Array,
             from repro.core.mutations import label_match_gather
             in_range &= label_match_gather(labels, filter_bytes, ids)
         masked = jnp.where(in_range, ids, -1)
-        return fn(queries, v, vec_sqnorm, masked, interpret=interpret)
+        return gather_l2_chunked(queries, v, vec_sqnorm, masked,
+                                 interpret=interpret)
 
     # gather wrappers return +inf for masked ids; beam_search skips its pass
     score.self_masking = True

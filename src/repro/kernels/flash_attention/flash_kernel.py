@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 Array = jax.Array
 
 _NEG = float(-1e30)
@@ -118,7 +116,7 @@ def flash_attention_pallas(q: Array, k: Array, v: Array, *, causal: bool,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -292,7 +290,7 @@ def flash_attention_fwd_pallas(q, k, v, *, causal, window=0, q_offset=0,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -335,7 +333,7 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, *, causal, window=0,
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -379,7 +377,7 @@ def flash_attention_bwd_pallas(q, k, v, o, lse, do, *, causal, window=0,
             pltpu.VMEM((block_kv, dh), jnp.float32),
             pltpu.VMEM((block_kv, dh), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -12,6 +12,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.flash_attention.flash_kernel import (
     flash_attention_bwd_pallas,
     flash_attention_fwd_pallas,
@@ -19,10 +20,6 @@ from repro.kernels.flash_attention.flash_kernel import (
 )
 
 Array = jax.Array
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -65,8 +62,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
     Layout adapter around the kernels (which want (B, H, S, Dh)).
     Differentiable (custom_vjp over the Pallas backward kernels).
     """
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -78,8 +74,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
 def flash_attention_inference(q: Array, k: Array, v: Array, **kw) -> Array:
     """Forward-only variant (no LSE output buffer)."""
     interpret = kw.pop("interpret", None)
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     out = flash_attention_pallas(
         jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
         interpret=interpret, **kw)

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.rabitq import RaBitQCodes, RaBitQQuery
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.rabitq_dot.rabitq_kernel import (
     rabitq_distance_pallas,
     rabitq_gather_distance_pallas,
@@ -15,10 +16,6 @@ from repro.kernels.rabitq_dot.rabitq_kernel import (
 )
 
 Array = jax.Array
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: Array, mult: int, axis: int, value=0) -> Array:
@@ -30,28 +27,27 @@ def _pad_to(x: Array, mult: int, axis: int, value=0) -> Array:
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _q_to_code_width(q_rot: Array, p: int, bits: int) -> Array:
+    """Zero-pad rotated queries to the unpacked width P * 8//bits (padded
+    dims meet zero codes and contribute nothing)."""
+    q = q_rot.astype(jnp.float32)
+    return jnp.pad(q, ((0, 0), (0, p * (8 // bits) - q.shape[1])))
+
+
 @partial(jax.jit, static_argnames=("bits", "block_q", "block_c", "interpret"))
 def rabitq_distance(packed: Array, data_add: Array, data_rescale: Array,
                     q_rot: Array, query_add: Array, query_sumq: Array, *,
                     bits: int, block_q: int = 128, block_c: int = 256,
                     interpret: bool | None = None) -> Array:
     """All-candidates estimated distances: (Q, C) from packed codes."""
-    if interpret is None:
-        interpret = _auto_interpret()
-    qn, d = q_rot.shape
-    cn = packed.shape[0]
-    cpb = 8 // bits
-    # pad packed width to a 128-lane tile; pad q dims to match (zeros inert)
-    p_pad = _pad_to(packed, 128, 1)
-    d_need = p_pad.shape[1] * cpb
-    q_pad = _pad_to(q_rot.astype(jnp.float32), d_need - d + d if d_need > d
-                    else 1, 1) if d_need > d else q_rot.astype(jnp.float32)
-    if q_pad.shape[1] < d_need:
-        q_pad = jnp.pad(q_pad, ((0, 0), (0, d_need - q_pad.shape[1])))
+    interpret = resolve_interpret(interpret)
+    qn = q_rot.shape[0]
+    cn, p = packed.shape
+    q_pad = _q_to_code_width(q_rot, p, bits)
     q_pad = _pad_to(q_pad, block_q, 0)
     qadd = _pad_to(query_add, block_q, 0)
     qsum = _pad_to(query_sumq, block_q, 0)
-    p_pad = _pad_to(p_pad, block_c, 0)
+    p_pad = _pad_to(packed, block_c, 0)
     dadd = _pad_to(data_add, block_c, 0)
     drs = _pad_to(data_rescale, block_c, 0)
     out = rabitq_distance_pallas(p_pad, dadd, drs, q_pad, qadd, qsum,
@@ -67,19 +63,13 @@ def rabitq_gather_distance(cand_packed: Array, cand_add: Array,
                            block_q: int = 8, interpret: bool | None = None
                            ) -> Array:
     """Beam-search form: (Q, K, P) candidate codes -> (Q, K) estimates."""
-    if interpret is None:
-        interpret = _auto_interpret()
-    qn, k, p = cand_packed.shape
-    d = q_rot.shape[1]
-    cpb = 8 // bits
-    p_pad = _pad_to(cand_packed, 128, 2)
-    d_need = p_pad.shape[2] * cpb
-    q_pad = q_rot.astype(jnp.float32)
-    if q_pad.shape[1] < d_need:
-        q_pad = jnp.pad(q_pad, ((0, 0), (0, d_need - q_pad.shape[1])))
+    interpret = resolve_interpret(interpret)
+    p = cand_packed.shape[2]
+    qn = q_rot.shape[0]
+    q_pad = _q_to_code_width(q_rot, p, bits)
     q_pad = _pad_to(q_pad, block_q, 0)
     out = rabitq_gather_distance_pallas(
-        _pad_to(p_pad, block_q, 0),
+        _pad_to(cand_packed, block_q, 0),
         _pad_to(cand_add, block_q, 0),
         _pad_to(cand_rescale, block_q, 0),
         q_pad,
@@ -101,19 +91,13 @@ def rabitq_search_step(cand_packed: Array, cand_add: Array,
 
     live: optional (Q, K) per-candidate tombstone flags (1 = live, 0 = dead
     -> +inf); omitted means every in-range id is live."""
-    if interpret is None:
-        interpret = _auto_interpret()
-    qn, k, p = cand_packed.shape
-    cpb = 8 // bits
+    interpret = resolve_interpret(interpret)
+    qn, _, p = cand_packed.shape
     if live is None:
         live = jnp.ones_like(ids, dtype=jnp.int32)
-    p_pad = _pad_to(cand_packed, 128, 2)
-    d_need = p_pad.shape[2] * cpb
-    q_pad = q_rot.astype(jnp.float32)
-    if q_pad.shape[1] < d_need:
-        q_pad = jnp.pad(q_pad, ((0, 0), (0, d_need - q_pad.shape[1])))
+    q_pad = _q_to_code_width(q_rot, p, bits)
     out = rabitq_search_step_pallas(
-        _pad_to(p_pad, block_q, 0),
+        _pad_to(cand_packed, block_q, 0),
         _pad_to(cand_add, block_q, 0),
         _pad_to(cand_rescale, block_q, 0),
         _pad_to(ids.astype(jnp.int32), block_q, 0, value=-1),

@@ -26,22 +26,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 Array = jax.Array
 
 
 def _unpack_tile(packed_u8: Array, bits: int) -> Array:
-    """(TC, P) uint8 -> (TC, P * 8//bits) f32, little-endian per byte."""
+    """(TC, P) uint8 -> (TC, P * 8//bits) f32, little-endian per byte.
+
+    Code d is bits*(d % cpb) of byte d // cpb. Each byte is spread to its
+    cpb code columns by a 0/1 matmul (bf16 in, f32 out: a byte <= 255 is
+    exact in bf16 and every output has one nonzero term), then shifted and
+    masked on the VPU. An interleaving (TC, P, cpb) -> (TC, D) reshape
+    would give its minor dim of cpb a whole lane tile in VMEM."""
     cpb = 8 // bits
-    mask = (1 << bits) - 1
-    p32 = packed_u8.astype(jnp.int32)
+    b32 = packed_u8.astype(jnp.int32).astype(jnp.float32)
     if cpb == 1:
-        return p32.astype(jnp.float32)
-    parts = [((p32 >> (bits * s)) & mask) for s in range(cpb)]
-    stacked = jnp.stack(parts, axis=-1)              # (TC, P, cpb)
-    tc, p, _ = stacked.shape
-    return stacked.reshape(tc, p * cpb).astype(jnp.float32)
+        return b32
+    p = packed_u8.shape[1]
+    d = p * cpb
+    byte = jax.lax.broadcasted_iota(jnp.int32, (p, d), 0)
+    code = jax.lax.broadcasted_iota(jnp.int32, (p, d), 1)
+    spread = jnp.where(byte == code // cpb, 1.0, 0.0).astype(jnp.bfloat16)
+    rep = jax.lax.dot_general(
+        b32.astype(jnp.bfloat16), spread, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)          # (TC, D) byte values
+    shift = bits * (jax.lax.broadcasted_iota(jnp.int32, (1, d), 1) % cpb)
+    codes = (rep.astype(jnp.int32) >> shift) & ((1 << bits) - 1)
+    return codes.astype(jnp.float32)
 
 
 def _rabitq_kernel(q_ref, qadd_ref, qsum_ref, codes_ref, dadd_ref, drs_ref,
@@ -57,10 +67,10 @@ def _rabitq_kernel(q_ref, qadd_ref, qsum_ref, codes_ref, dadd_ref, drs_ref,
 
 def _rabitq_gather_kernel(q_ref, qadd_ref, qsum_ref, codes_ref, dadd_ref,
                           drs_ref, o_ref, *, bits: int):
-    # codes_ref: (TQ, K, P) — per-query candidate tiles (bulk-gathered)
-    tq, k, p = codes_ref.shape
-    codes = _unpack_tile(codes_ref[...].reshape(tq * k, p), bits)
-    codes = codes.reshape(tq, k, -1)                 # (TQ, K, D)
+    # codes_ref: (TQ*K, P) — per-query candidate tiles (bulk-gathered),
+    # flattened outside the kernel
+    tq, k = o_ref.shape
+    codes = _unpack_tile(codes_ref[...], bits).reshape(tq, k, -1)
     dot = jax.lax.dot_general(
         codes, q_ref[...], (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)          # (TQ, K)
@@ -81,9 +91,8 @@ def _rabitq_search_step_kernel(nvalid_ref, q_ref, qadd_ref, qsum_ref,
     byte gather rides along with the packed-code gather outside the kernel,
     the mask itself is fused here.
     """
-    tq, k, p = codes_ref.shape
-    codes = _unpack_tile(codes_ref[...].reshape(tq * k, p), bits)
-    codes = codes.reshape(tq, k, -1)                 # (TQ, K, D)
+    tq, k = o_ref.shape
+    codes = _unpack_tile(codes_ref[...], bits).reshape(tq, k, -1)
     dot = jax.lax.dot_general(
         codes, q_ref[...], (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)          # (TQ, K)
@@ -115,17 +124,17 @@ def rabitq_gather_distance_pallas(cand_packed: Array, cand_add: Array,
             pl.BlockSpec((block_q, d), lambda i: (i, 0)),
             pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, k, p), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block_q * k, p), lambda i: (i, 0)),
             pl.BlockSpec((block_q, k), lambda i: (i, 0)),
             pl.BlockSpec((block_q, k), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_q, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((qn, k), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(q_rot, query_add.reshape(-1, 1), query_sumq.reshape(-1, 1),
-      cand_packed, cand_add, cand_rescale)
+      cand_packed.reshape(qn * k, p), cand_add, cand_rescale)
 
 
 def rabitq_search_step_pallas(cand_packed: Array, cand_add: Array,
@@ -155,18 +164,18 @@ def rabitq_search_step_pallas(cand_packed: Array, cand_add: Array,
             pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_q, k), lambda i: (i, 0)),
             pl.BlockSpec((block_q, k), lambda i: (i, 0)),
-            pl.BlockSpec((block_q, k, p), lambda i: (i, 0, 0)),
+            pl.BlockSpec((block_q * k, p), lambda i: (i, 0)),
             pl.BlockSpec((block_q, k), lambda i: (i, 0)),
             pl.BlockSpec((block_q, k), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_q, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((qn, k), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(n_valid.reshape(-1), q_rot, query_add.reshape(-1, 1),
-      query_sumq.reshape(-1, 1), ids, live, cand_packed, cand_add,
-      cand_rescale)
+      query_sumq.reshape(-1, 1), ids, live, cand_packed.reshape(qn * k, p),
+      cand_add, cand_rescale)
 
 
 def rabitq_distance_pallas(packed: Array, data_add: Array, data_rescale: Array,
@@ -196,7 +205,7 @@ def rabitq_distance_pallas(packed: Array, data_add: Array, data_rescale: Array,
         ],
         out_specs=pl.BlockSpec((block_q, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, cn), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(q_rot, query_add.reshape(-1, 1), query_sumq.reshape(-1, 1),

@@ -25,18 +25,16 @@ from repro.core.beam_search import (
 )
 from repro.core.rabitq import RaBitQCodes, RaBitQQuery, rabitq_estimate
 from repro.core.vamana import VamanaGraph
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.search_step.search_step_kernel import (
     fused_hop_pallas,
+    fused_planes,
     fused_search_pallas,
 )
 
 Array = jax.Array
 
 _INF = float("inf")
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_rows(x: Array, mult: int, value) -> Array:
@@ -77,8 +75,7 @@ def fused_beam_search(graph: VamanaGraph, *, mode: str, beam_width: int,
     (SearchTelemetry; the ref oracle's exact values) — off, the kernels
     are launched with zero extra outputs.
     """
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     if mode not in ("hop", "megakernel"):
         raise ValueError(f"mode must be 'hop' or 'megakernel', got {mode!r}")
     quantized = codes is not None
@@ -114,13 +111,17 @@ def fused_beam_search(graph: VamanaGraph, *, mode: str, beam_width: int,
     # exclude-mode liveness is gathered in-kernel; traverse mode leaves the
     # walk alone and filters only the final frontier (shared epilogue)
     use_tomb = tombstone_bits is not None and not traverse_deleted
-    tomb = tombstone_bits.reshape(-1, 1) if use_tomb else None
     # same split for the label filter: exclude mode rides the kernel
     # epilogue, traverse mode only label-filters the final frontier
     use_filt = labels is not None and filter_exclude
-    lab = labels if use_filt else None
-    fb = (jnp.asarray(filter_bytes, jnp.int32).reshape(-1)
-          if use_filt else None)
+    # lane-dense HBM views of every gathered plane, built once per search
+    views, planes, fbw = fused_planes(
+        graph.adjacency, data, meta,
+        tombstone_bits.reshape(-1) if use_tomb else None,
+        labels if use_filt else None,
+        (jnp.asarray(filter_bytes, jnp.int32).reshape(-1)
+         if use_filt else None),
+        dim=q.shape[1])
 
     # ---- init frontier (medoid in slot 0), padded to the query block
     f_ids = jnp.full((num_q, beam_width), -1, jnp.int32)
@@ -137,15 +138,15 @@ def fused_beam_search(graph: VamanaGraph, *, mode: str, beam_width: int,
 
     sched = jnp.asarray(
         expand_schedule(beam_schedule, beam_width, max_iters), jnp.int32)
-    kern = dict(quantized=quantized, bits=bits, block_q=block_q,
-                telemetry=telemetry, interpret=interpret)
+    kern = dict(quantized=quantized, bits=bits, use_tomb=use_tomb,
+                use_filt=use_filt, block_q=block_q, telemetry=telemetry,
+                interpret=interpret)
 
     tel = None
     if mode == "megakernel":
         out = fused_search_pallas(
-            f_ids, f_dists, f_vis, sched, q, qa, qb, graph.adjacency,
-            data, meta, tomb, lab, fb, graph.n_valid,
-            max_iters=max_iters, **kern)
+            f_ids, f_dists, f_vis, sched, q, qa, qb, views, planes, fbw,
+            graph.n_valid, max_iters=max_iters, **kern)
         f_ids, f_dists, hops = out[:3]
         hops = hops[:, 0]
         if telemetry:
@@ -168,8 +169,8 @@ def fused_beam_search(graph: VamanaGraph, *, mode: str, beam_width: int,
         def body(st):
             it, fi, fd, fv, hops = st[:5]
             hop = fused_hop_pallas(
-                fi, fd, fv, sched[it], q, qa, qb, graph.adjacency,
-                data, meta, tomb, lab, fb, graph.n_valid, **kern)
+                fi, fd, fv, sched[it], q, qa, qb, views, planes, fbw,
+                graph.n_valid, **kern)
             nfi, nfd, nfv, inc = hop[:4]
             out = (it + 1, nfi, nfd, nfv, hops + inc[:, 0])
             if telemetry:

@@ -7,21 +7,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import resolve_interpret
 from repro.kernels.topk.topk_kernel import topk_pallas
 
 Array = jax.Array
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("k", "block_q", "interpret"))
 def topk(dists: Array, ids: Array, k: int, *, block_q: int = 8,
          interpret: bool | None = None) -> tuple[Array, Array]:
     """k smallest distances per row with their ids, ascending order."""
-    if interpret is None:
-        interpret = _auto_interpret()
+    interpret = resolve_interpret(interpret)
     qn, c = dists.shape
     pad_q = (-qn) % block_q
     if pad_q:

@@ -20,30 +20,34 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 Array = jax.Array
 
 
-def _topk_kernel(d_ref, i_ref, od_ref, oi_ref, scratch_ref, *, k: int):
-    scratch_ref[...] = d_ref[...]
+def _topk_kernel(d_ref, i_ref, od_ref, oi_ref, *, k: int):
     ids = i_ref[...]
-    tq, c = scratch_ref.shape
+    tq, c = d_ref.shape
     col = jax.lax.broadcasted_iota(jnp.int32, (tq, c), 1)
+    ocol = jax.lax.broadcasted_iota(jnp.int32, (tq, k), 1)
 
-    def step(s, _):
-        d = scratch_ref[...]
+    def step(s, carry):
+        d, od, oi = carry
         m = jnp.min(d, axis=1, keepdims=True)                  # (TQ, 1)
-        is_min = d == m
         # first-occurrence argmin via iota trick (no cross-lane shuffle)
-        first = jnp.min(jnp.where(is_min, col, c), axis=1, keepdims=True)
+        first = jnp.min(jnp.where(d == m, col, c), axis=1, keepdims=True)
         sel = col == first
-        od_ref[:, s] = m[:, 0]
-        oi_ref[:, s] = jnp.sum(jnp.where(sel, ids, 0), axis=1)
-        scratch_ref[...] = jnp.where(sel, jnp.inf, d)
-        return 0
+        pick = jnp.sum(jnp.where(sel, ids, 0), axis=1, keepdims=True)
+        # pass s lands in column s by a one-hot select: Mosaic stores only
+        # at lane offsets it can prove, and s is a loop counter
+        at = ocol == s
+        od = jnp.where(at, m, od)
+        oi = jnp.where(at, pick, oi)
+        return jnp.where(sel, jnp.inf, d), od, oi
 
-    jax.lax.fori_loop(0, k, step, 0)
+    init = (d_ref[...], jnp.full((tq, k), jnp.inf, jnp.float32),
+            jnp.full((tq, k), -1, jnp.int32))
+    _, od, oi = jax.lax.fori_loop(0, k, step, init)
+    od_ref[...] = od
+    oi_ref[...] = oi
 
 
 def topk_pallas(dists: Array, ids: Array, k: int, *, block_q: int = 8,
@@ -66,8 +70,7 @@ def topk_pallas(dists: Array, ids: Array, k: int, *, block_q: int = 8,
             jax.ShapeDtypeStruct((qn, k), jnp.float32),
             jax.ShapeDtypeStruct((qn, k), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_q, c), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(dists, ids)

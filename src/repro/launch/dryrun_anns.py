@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ANNS_DATASETS
 from repro.core.distributed import ShardSpec, merge_topk, sharded_search_fn
 from repro.core.index_core import IndexCore
@@ -146,7 +145,7 @@ def lower_anns_cell(ds_name: str, variant: str, mesh, *, bits: int = 4,
             return merge_topk(ids.astype(jnp.int32), -neg,
                               spec.row_axes, K)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             bf, mesh=mesh,
             in_specs=(row_spec, sc_spec, sc_spec, q_spec),
             out_specs=(q_spec, q_spec), check_vma=False)

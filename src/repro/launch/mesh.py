@@ -13,19 +13,13 @@ over it).
 from __future__ import annotations
 
 import jax
-
-try:
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no explicit axis types; Auto is implied
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with the AxisType compat shim (0.4.x has no
-    axis_types kwarg) — the ONE mesh constructor; benchmarks, examples,
-    and tests that build ad-hoc meshes route through here."""
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axes — the ONE mesh constructor;
+    benchmarks, examples, and tests that build ad-hoc meshes route
+    through here."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init
 from repro.models.sharding_ctx import constrain
@@ -246,7 +245,7 @@ def _moe_shard_map(params: dict, x: Array, cfg: ModelConfig, mesh
         P(batch_axes if batch_axes else None, seq_axis, None),  # x
     )
     out_specs = (P(batch_axes if batch_axes else None, seq_axis, None), P())
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     return fn(params["router"], params["w_gate"], params["w_up"],
               params["w_down"], x)

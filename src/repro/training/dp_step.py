@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.model import loss_fn
 from repro.training.compression import compressed_psum
@@ -67,7 +66,7 @@ def make_dp_train_step_compressed(cfg: ModelConfig, opt: OptimizerConfig,
         return TrainState(params=params, opt_state=opt_state), out_metrics
 
     batch_spec = P(data_axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), {"tokens": batch_spec, "labels": batch_spec},
                   P(data_axes)),

@@ -12,8 +12,8 @@ pytestmark = pytest.mark.multidevice
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# prepended to every subprocess script: the shared AxisType-compat mesh
-# constructor (import-safe before device init)
+# prepended to every subprocess script: the shared mesh constructor
+# (import-safe before device init)
 PREAMBLE = """
 import jax
 from repro.launch.mesh import make_mesh
@@ -496,9 +496,8 @@ def test_compressed_psum_close_to_exact():
             return exact, approx
 
         keys = jax.random.split(jax.random.PRNGKey(0), 8)
-        from repro.compat import shard_map
-        fn = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                       out_specs=(P(), P()), check_vma=False)
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                           out_specs=(P(), P()), check_vma=False)
         exact, approx = fn(g, keys)
         rel = float(jnp.max(jnp.abs(exact - approx))
                     / (jnp.max(jnp.abs(exact)) + 1e-9))

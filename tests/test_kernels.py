@@ -64,18 +64,16 @@ def test_pairwise_l2_block_sweep():
 
 
 # ------------------------------------------------------------- gather forms
-@pytest.mark.parametrize("strategy", ["tiled", "chunked"])
 @pytest.mark.parametrize("q,k,d,n", [
     (8, 16, 128, 200),
     (33, 7, 96, 100),
     (4, 64, 960, 64),
 ])
-def test_gather_l2(strategy, q, k, d, n):
+def test_gather_l2(q, k, d, n):
     qv, db = randn(q, d), randn(n, d)
     db_sq = jnp.sum(db * db, axis=-1)
     ids = jnp.asarray(RNG.integers(-1, n, (q, k)), jnp.int32)
-    fn = dops.gather_l2_tiled if strategy == "tiled" else dops.gather_l2_chunked
-    out = fn(qv, db, db_sq, ids)
+    out = dops.gather_l2_chunked(qv, db, db_sq, ids)
     ref = gather_l2_ref(qv, db, ids)
     finite = np.isfinite(np.asarray(ref))
     assert (np.isfinite(np.asarray(out)) == finite).all()
