@@ -195,19 +195,21 @@ def finalize_frontier(f_ids, f_dists, tombstone_bits, labels=None,
     function, so the 'never return a deleted id' invariant (and its label
     twin: 'never return an out-of-filter id', in BOTH filter modes) has a
     single definition."""
-    drop = None
-    if tombstone_bits is not None:
-        from repro.core.mutations import bitmap_gather  # lazy: no cycle
-        drop = bitmap_gather(tombstone_bits, f_ids)
-    if labels is not None:
-        from repro.core.mutations import label_match_gather
-        miss = ~label_match_gather(labels, filter_bytes, f_ids) & (f_ids >= 0)
-        drop = miss if drop is None else (drop | miss)
-    if drop is not None:
-        f_dists = jnp.where(drop, _INF, f_dists)
-        f_dists, f_ids = jax.lax.sort((f_dists, f_ids), dimension=1,
-                                      is_stable=True, num_keys=1)
-    f_ids = jnp.where(jnp.isfinite(f_dists), f_ids, -1)
+    with jax.named_scope("search.finalize"):
+        drop = None
+        if tombstone_bits is not None:
+            from repro.core.mutations import bitmap_gather  # no cycle
+            drop = bitmap_gather(tombstone_bits, f_ids)
+        if labels is not None:
+            from repro.core.mutations import label_match_gather
+            miss = (~label_match_gather(labels, filter_bytes, f_ids)
+                    & (f_ids >= 0))
+            drop = miss if drop is None else (drop | miss)
+        if drop is not None:
+            f_dists = jnp.where(drop, _INF, f_dists)
+            f_dists, f_ids = jax.lax.sort((f_dists, f_ids), dimension=1,
+                                          is_stable=True, num_keys=1)
+        f_ids = jnp.where(jnp.isfinite(f_dists), f_ids, -1)
     return f_ids, f_dists
 
 
@@ -339,99 +341,112 @@ def beam_search(graph: VamanaGraph, score_fn: ScoreFn, num_queries: int | None =
         it = st[0]
         return (it < max_iters) & has_work(st)
 
+    # the hop's phases carry named scopes (`hop.*`): they change only op
+    # metadata, so a profiler trace maps each fusion to its phase
     def body(st):
         it, f_ids, f_dists, f_vis, vlog, vdlog, hops = st[:7]
         l_width = f_ids.shape[1]
-        unvis = (f_ids >= 0) & ~f_vis                      # (Q, L)
-        # frontier is distance-sorted => first unvisited are the closest;
-        # pick the first e_exp unvisited positions per query
-        order = jnp.where(unvis, jnp.arange(l_width)[None, :], l_width)
-        picks = jnp.sort(order, axis=1)[:, :e_exp]         # (Q, E)
-        pick_valid = picks < l_width
-        safe_picks = jnp.minimum(picks, l_width - 1)
-        cur = jnp.take_along_axis(f_ids, safe_picks, axis=1)   # (Q, E)
-        cur = jnp.where(pick_valid, cur, -1)
-        cur_d = jnp.take_along_axis(f_dists, safe_picks, axis=1)
-        active = pick_valid[:, 0]
+        with jax.named_scope("hop.select"):
+            unvis = (f_ids >= 0) & ~f_vis                  # (Q, L)
+            # frontier is distance-sorted => first unvisited are the
+            # closest; pick the first e_exp unvisited positions per query
+            order = jnp.where(unvis, jnp.arange(l_width)[None, :], l_width)
+            picks = jnp.sort(order, axis=1)[:, :e_exp]     # (Q, E)
+            pick_valid = picks < l_width
+            safe_picks = jnp.minimum(picks, l_width - 1)
+            cur = jnp.take_along_axis(f_ids, safe_picks, axis=1)  # (Q, E)
+            cur = jnp.where(pick_valid, cur, -1)
+            cur_d = jnp.take_along_axis(f_dists, safe_picks, axis=1)
+            active = pick_valid[:, 0]
 
-        # mark picked as visited (scatter E bits per row)
-        hit = jnp.any(
-            jnp.arange(l_width)[None, None, :] == picks[:, :, None], axis=1)
-        f_vis = f_vis | (hit & unvis)
+            # mark picked as visited (scatter E bits per row)
+            hit = jnp.any(jnp.arange(l_width)[None, None, :]
+                          == picks[:, :, None], axis=1)
+            f_vis = f_vis | (hit & unvis)
 
-        vlog = vlog.at[:, it].set(cur[:, 0])
-        vdlog = vdlog.at[:, it].set(jnp.where(active, cur_d[:, 0], _INF))
-        hops = hops + jnp.sum(pick_valid, axis=1).astype(jnp.int32)
+            vlog = vlog.at[:, it].set(cur[:, 0])
+            vdlog = vdlog.at[:, it].set(jnp.where(active, cur_d[:, 0], _INF))
+            hops = hops + jnp.sum(pick_valid, axis=1).astype(jnp.int32)
 
-        # expand: gather neighbor lists of all picked nodes
-        nbrs = adj[jnp.maximum(cur, 0)]                    # (Q, E, R)
-        nbrs = jnp.where((cur >= 0)[:, :, None], nbrs, -1)
-        nbrs = nbrs.reshape(cur.shape[0], -1)              # (Q, E*R)
-        if e_exp > 1:
-            # different expanded nodes may share neighbors: dedup within
-            # the candidate row (order is irrelevant — the merge re-sorts)
-            big = jnp.int32(2**30)
-            key = jnp.sort(jnp.where(nbrs >= 0, nbrs, big), axis=1)
-            dup_in_row = jnp.concatenate(
-                [jnp.zeros_like(key[:, :1], dtype=jnp.bool_),
-                 key[:, 1:] == key[:, :-1]], axis=1)
-            nbrs = jnp.where(dup_in_row | (key >= big), -1, key)
-        # drop out-of-range and frontier duplicates
-        in_range = (nbrs >= 0) & (nbrs < n_valid)
-        dup = jnp.any(nbrs[:, :, None] == f_ids[:, None, :], axis=2)
-        valid = in_range & ~dup
-        if count_masked or exclude_in_body:
-            dead = bitmap_gather(tombstone_bits, nbrs) & valid
-        if exclude_in_body:
-            valid &= ~dead
-        if count_fmasked or filter_in_body:
-            # tombstone test FIRST: a dead candidate counts once in
-            # `masked`, whatever the filter says about it
-            fmiss = ~label_match_gather(labels, filter_bytes, nbrs) & valid
-            if (count_masked or exclude_in_body) and not exclude_in_body:
-                fmiss &= ~dead
-        if filter_in_body:
-            valid &= ~fmiss
-        nbrs = jnp.where(valid, nbrs, -1)
-        if telemetry:
-            scored, masked, dups, occ_log = st[7:]
-            dead_n = (jnp.sum(dead, axis=1).astype(jnp.int32)
-                      if count_masked else jnp.int32(0))
-            fmiss_n = (jnp.sum(fmiss, axis=1).astype(jnp.int32)
-                       if count_fmasked else jnp.int32(0))
-            # counters naturally stay 0 on converged rows: cur = -1 there,
-            # so every neighbor is -1 and in_range is all-False
-            scored = scored + (jnp.sum(valid, axis=1).astype(jnp.int32)
-                               - (0 if exclude_in_body else dead_n)
-                               - (0 if filter_in_body else fmiss_n))
-            masked = masked + dead_n + fmiss_n
-            dups = dups + jnp.sum(in_range & dup, axis=1).astype(jnp.int32)
+        with jax.named_scope("hop.adjacency"):
+            # expand: gather neighbor lists of all picked nodes
+            nbrs = adj[jnp.maximum(cur, 0)]                # (Q, E, R)
+            nbrs = jnp.where((cur >= 0)[:, :, None], nbrs, -1)
+            nbrs = nbrs.reshape(cur.shape[0], -1)          # (Q, E*R)
+        with jax.named_scope("hop.dedup"):
+            if e_exp > 1:
+                # different expanded nodes may share neighbors: dedup
+                # within the candidate row (order is irrelevant — the
+                # merge re-sorts)
+                big = jnp.int32(2**30)
+                key = jnp.sort(jnp.where(nbrs >= 0, nbrs, big), axis=1)
+                dup_in_row = jnp.concatenate(
+                    [jnp.zeros_like(key[:, :1], dtype=jnp.bool_),
+                     key[:, 1:] == key[:, :-1]], axis=1)
+                nbrs = jnp.where(dup_in_row | (key >= big), -1, key)
+            # drop out-of-range and frontier duplicates
+            in_range = (nbrs >= 0) & (nbrs < n_valid)
+            dup = jnp.any(nbrs[:, :, None] == f_ids[:, None, :], axis=2)
+            valid = in_range & ~dup
+            if count_masked or exclude_in_body:
+                dead = bitmap_gather(tombstone_bits, nbrs) & valid
+            if exclude_in_body:
+                valid &= ~dead
+            if count_fmasked or filter_in_body:
+                # tombstone test FIRST: a dead candidate counts once in
+                # `masked`, whatever the filter says about it
+                fmiss = ~label_match_gather(labels, filter_bytes, nbrs) & valid
+                if (count_masked or exclude_in_body) and not exclude_in_body:
+                    fmiss &= ~dead
+            if filter_in_body:
+                valid &= ~fmiss
+            nbrs = jnp.where(valid, nbrs, -1)
+            if telemetry:
+                scored, masked, dups, occ_log = st[7:]
+                dead_n = (jnp.sum(dead, axis=1).astype(jnp.int32)
+                          if count_masked else jnp.int32(0))
+                fmiss_n = (jnp.sum(fmiss, axis=1).astype(jnp.int32)
+                           if count_fmasked else jnp.int32(0))
+                # counters naturally stay 0 on converged rows: cur = -1
+                # there, so every neighbor is -1 and in_range is
+                # all-False
+                scored = scored + (jnp.sum(valid, axis=1).astype(jnp.int32)
+                                   - (0 if exclude_in_body else dead_n)
+                                   - (0 if filter_in_body else fmiss_n))
+                masked = masked + dead_n + fmiss_n
+                dups = dups + jnp.sum(in_range & dup,
+                                      axis=1).astype(jnp.int32)
 
-        d = score_fn(nbrs)                                 # (Q, E*R)
-        if not self_masking:
-            # invalid entries carry id -1 (set above), so a self-masking
-            # scorer has already written +inf for exactly `~valid`
-            d = jnp.where(valid, d, _INF)
+        with jax.named_scope("hop.score"):
+            d = score_fn(nbrs)                             # (Q, E*R)
+            if not self_masking:
+                # invalid entries carry id -1 (set above), so a
+                # self-masking scorer has already written +inf for
+                # exactly `~valid`
+                d = jnp.where(valid, d, _INF)
 
-        f_ids, f_dists, f_vis = merge(
-            f_ids, f_dists, f_vis, nbrs, d, beam_width=l_width)
-        if sched is not None:
-            # narrow only rows that expanded work this hop: a converged
-            # row's frontier is frozen, so its results don't depend on how
-            # long the rest of the batch keeps iterating (and the fused
-            # megakernel — which retires converged blocks early — agrees)
-            ni, nd, nv = apply_beam_width(f_ids, f_dists, f_vis, sched[it])
-            act = jnp.any(pick_valid, axis=1)[:, None]
-            f_ids = jnp.where(act, ni, f_ids)
-            f_dists = jnp.where(act, nd, f_dists)
-            f_vis = jnp.where(act, nv, f_vis)
-        out = (it + 1, f_ids, f_dists, f_vis, vlog, vdlog, hops)
-        if telemetry:
-            # post-merge/narrow live slots, logged only for rows that
-            # expanded this hop (see SearchTelemetry docstring)
-            occ = jnp.sum(f_ids >= 0, axis=1).astype(jnp.int32)
-            occ_log = occ_log.at[:, it].set(jnp.where(active, occ, 0))
-            out = out + (scored, masked, dups, occ_log)
+        with jax.named_scope("hop.merge"):
+            f_ids, f_dists, f_vis = merge(
+                f_ids, f_dists, f_vis, nbrs, d, beam_width=l_width)
+            if sched is not None:
+                # narrow only rows that expanded work this hop: a
+                # converged row's frontier is frozen, so its results
+                # don't depend on how long the rest of the batch keeps
+                # iterating (and the fused megakernel — which retires
+                # converged blocks early — agrees)
+                ni, nd, nv = apply_beam_width(f_ids, f_dists, f_vis,
+                                              sched[it])
+                act = jnp.any(pick_valid, axis=1)[:, None]
+                f_ids = jnp.where(act, ni, f_ids)
+                f_dists = jnp.where(act, nd, f_dists)
+                f_vis = jnp.where(act, nv, f_vis)
+            out = (it + 1, f_ids, f_dists, f_vis, vlog, vdlog, hops)
+            if telemetry:
+                # post-merge/narrow live slots, logged only for rows that
+                # expanded this hop (see SearchTelemetry docstring)
+                occ = jnp.sum(f_ids >= 0, axis=1).astype(jnp.int32)
+                occ_log = occ_log.at[:, it].set(jnp.where(active, occ, 0))
+                out = out + (scored, masked, dups, occ_log)
         return out
 
     if fixed_trip:
@@ -497,7 +512,8 @@ def rerank_frontier(vectors: Array, vec_sqnorm: Array, queries: Array,
             score = make_exact_scorer(vectors, qt, None, vec_sqnorm)
             return jnp.where(it >= 0, score(it), _INF)
 
-    d = jax.lax.map(do_tile, (q_tiles, id_tiles))
+    with jax.named_scope("search.rerank"):
+        d = jax.lax.map(do_tile, (q_tiles, id_tiles))
     return d.reshape(-1, l)[:q_n]
 
 

@@ -225,7 +225,7 @@ def sharded_search_fn(mesh: Mesh, shard_spec: ShardSpec,
     tel_on = spec.telemetry == "on"
     filtered = spec.filtered
 
-    def local_search(core_stacked, queries, *maybe_fb):
+    def jasper_search(core_stacked, queries, *maybe_fb):
         if trace_counter is not None:
             trace_counter()
         core = _local_core(core_stacked)
@@ -258,7 +258,7 @@ def sharded_search_fn(mesh: Mesh, shard_spec: ShardSpec,
         in_specs = in_specs + (P(),)
         in_shardings = in_shardings + (NamedSharding(mesh, P()),)
     fn = jax.shard_map(
-        local_search, mesh=mesh,
+        jasper_search, mesh=mesh,
         in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return jax.jit(fn, in_shardings=in_shardings)
 
@@ -280,7 +280,7 @@ def sharded_traversal_fn(mesh: Mesh, shard_spec: ShardSpec,
     tel_on = spec.telemetry == "on"
     filtered = spec.filtered
 
-    def local_traverse(core_stacked, queries, *maybe_fb):
+    def jasper_search(core_stacked, queries, *maybe_fb):
         if trace_counter is not None:
             trace_counter()
         core = _local_core(core_stacked)
@@ -308,7 +308,7 @@ def sharded_traversal_fn(mesh: Mesh, shard_spec: ShardSpec,
         in_specs = in_specs + (P(),)
         in_shardings = in_shardings + (NamedSharding(mesh, P()),)
     fn = jax.shard_map(
-        local_traverse, mesh=mesh,
+        jasper_search, mesh=mesh,
         in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return jax.jit(fn, in_shardings=in_shardings)
 

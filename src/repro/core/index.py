@@ -517,18 +517,20 @@ class JasperIndex(SearchSurface):
         def build():
             plans = self.plans
 
+            # the program's stable name: `jit_jasper_search` in a
+            # profiler trace, whatever the spec
             if rspec.filtered:
-                def run(core, queries, fb):
+                def jasper_search(core, queries, fb):
                     plans.count_trace()   # runs at trace time only
                     return core_search(core, queries, spec=rspec,
                                        filter_tombstones=filt,
                                        filter_bytes=fb)
             else:
-                def run(core, queries):
+                def jasper_search(core, queries):
                     plans.count_trace()   # runs at trace time only
                     return core_search(core, queries, spec=rspec,
                                        filter_tombstones=filt)
-            return jax.jit(run)
+            return jax.jit(jasper_search)
 
         fn = self.plans.get(key, build)
         if rspec.rerank_source == "host":

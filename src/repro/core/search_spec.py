@@ -30,6 +30,9 @@ cf. the real-time adaptive multi-stream GPU ANNS system, arXiv:2408.02937):
     resolves the spec once, looks up (or builds) the jitted executable per
     query shape, and supports `submit()/drain()` double-buffered batching
     so a serving loop can overlap host scheduling with device search.
+  * `land` — the ONE host landing of a `SearchResult`, which records the
+    process-wide `session.*` counters (`obs.registry()`) beside the
+    dispatch counters `Searcher` records.
 
 Driver contract (both `JasperIndex` and `ShardedJasperIndex` satisfy it):
 `_prep_query`, `_filter_tombstones`, `generation`, `brute_force`, a
@@ -37,21 +40,26 @@ Driver contract (both `JasperIndex` and `ShardedJasperIndex` satisfy it):
 a callable `(queries, filter_bytes) -> (ids, dists, n_hops)` — with a
 fourth `SearchTelemetry` element iff the resolved spec has
 `telemetry="on"`. `filter_bytes` is the runtime label-filter operand
-(None unless the resolved spec has `filtered=True`).
+(None unless the resolved spec has `filtered=True`). The jitted search
+programs are named `jasper_search` (`jit_jasper_search` in a profiler
+trace), the host-tier rerank programs `jasper_rerank_host`.
 """
 
 from __future__ import annotations
 
 import json
 import numbers
+import time
 from collections import OrderedDict, deque
 from dataclasses import asdict, dataclass, fields, replace
 from typing import Any, NamedTuple
 
+import jax
 import numpy as np
 
 from repro.core.beam_search import MERGE_STRATEGIES
 from repro.core.mutations import N_LABELS, filter_to_bytes
+from repro.obs.metrics import registry as obs_registry
 from repro.obs.tracing import span as obs_span
 
 SPEC_VERSION = 1
@@ -628,12 +636,22 @@ class Searcher:
 
     # ----------------------------------------------------------- execution
     def _dispatch(self, queries) -> SearchResult:
+        """Prep, plan lookup and async enqueue of one batch. Records
+        `session.dispatches` and `session.dispatch_s` (host seconds) for
+        a dispatch that did not trace; one that did (`PlanCache.stats
+        .traces` moved, which the service snapshot exports as
+        `plan_cache.traces`) is left out, so compile time stays out of
+        host time."""
         idx = self.index
+        traces = idx.plans.stats.traces
+        t0 = time.perf_counter()
         q = idx._prep_query(queries)
         generation = idx.generation
         plan = idx._search_plan(self.resolved, q.shape,
                                 idx._filter_tombstones)
         out = plan(q, self._filter_bytes)
+        if idx.plans.stats.traces == traces:
+            _count(dispatches=1, dispatch_s=time.perf_counter() - t0)
         # plans return (ids, dists, n_hops) — plus a SearchTelemetry
         # fourth element iff the resolved spec has telemetry on
         ids, dists, n_hops = out[:3]
@@ -658,14 +676,7 @@ class Searcher:
         out = []
         with obs_span("searcher.drain", pending=len(self._inflight)):
             while self._inflight and (limit is None or len(out) < limit):
-                r = self._inflight.popleft()
-                tel = r.telemetry
-                if tel is not None:
-                    tel = type(tel)(*(np.asarray(t) for t in tel))
-                out.append(SearchResult(
-                    ids=np.asarray(r.ids), dists=np.asarray(r.dists),
-                    n_hops=np.asarray(r.n_hops), generation=r.generation,
-                    telemetry=tel, estimated=r.estimated))
+                out.append(land(self._inflight.popleft()))
         return out
 
     @property
@@ -676,6 +687,61 @@ class Searcher:
     def cache_stats(self) -> CacheStats:
         """The index's shared plan-cache counters (hits/misses/traces)."""
         return self.index.plans.stats
+
+
+# ---------------------------------------------------------------------------
+# Session counters — recorded where batches are dispatched and landed
+# ---------------------------------------------------------------------------
+
+def _count(**deltas) -> None:
+    """Add to the process-wide `session.<name>` counters."""
+    reg = obs_registry()
+    for name, delta in deltas.items():
+        reg.counter(f"session.{name}").inc(delta)
+
+
+def land(res: SearchResult) -> SearchResult:
+    """Host-land one search batch: THE landing of a `SearchResult`
+    (`Searcher.drain`, `AnnsService.search`, the scheduler's harvest).
+
+    Blocks until the batch is ready on the device (span
+    `searcher.wait`), then copies ids, dists, n_hops and telemetry to
+    host numpy arrays (span `searcher.land`). Records into the
+    process-wide registry (`obs.registry()`), per batch:
+
+    session.batches   1
+    session.rows      rows the loop ran (padding included)
+    session.hops      sum of n_hops
+    session.trips     max of n_hops: on the unfused while_loop at
+                      expand=1 every row is active from trip 0 until it
+                      converges, so this is the loop's trip count; on
+                      other lanes, the slowest row's hop count
+    session.wait_s    seconds blocked until the batch was ready
+    session.land_s    seconds of the device-to-host copies (the wait
+                      excluded)
+
+    `hops / (rows * trips)` is the share of row-trips that expanded a
+    node, the rest waiting on the batch's slowest rows; `wait_s` near
+    zero per batch means the device finished before the host asked, so
+    the host sets the pace (docs/observability.md).
+    """
+    tel = res.telemetry
+    t0 = time.perf_counter()
+    with obs_span("searcher.wait"):
+        jax.block_until_ready((res.ids, res.dists, res.n_hops, tel))
+    t1 = time.perf_counter()
+    with obs_span("searcher.land"):
+        if tel is not None:
+            tel = type(tel)(*(np.asarray(t) for t in tel))
+        out = res._replace(ids=np.asarray(res.ids),
+                           dists=np.asarray(res.dists),
+                           n_hops=np.asarray(res.n_hops), telemetry=tel)
+    t2 = time.perf_counter()
+    hops = out.n_hops
+    _count(batches=1, rows=hops.shape[0], hops=int(hops.sum()),
+           trips=int(hops.max()) if hops.size else 0,
+           wait_s=t1 - t0, land_s=t2 - t1)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -301,7 +301,7 @@ def build_host_rerank_plan(rspec, trace_counter=None):
     from repro.core.beam_search import rerank_frontier
 
     @jax.jit
-    def rerank(queries, frontier_ids, table, table_sqnorm):
+    def jasper_rerank_host(queries, frontier_ids, table, table_sqnorm):
         if trace_counter is not None:
             trace_counter()
         q_n, l = frontier_ids.shape
@@ -315,7 +315,7 @@ def build_host_rerank_plan(rspec, trace_counter=None):
         si = jnp.where(jnp.isfinite(sd), si, -1)
         return si[:, :rspec.k], sd[:, :rspec.k]
 
-    return rerank
+    return jasper_rerank_host
 
 
 def build_sharded_host_rerank_plan(rspec, *, axis_sizes: tuple,
@@ -341,7 +341,8 @@ def build_sharded_host_rerank_plan(rspec, *, axis_sizes: tuple,
     from repro.core.beam_search import rerank_frontier
 
     @jax.jit
-    def rerank(queries, frontier_ids, table, table_sqnorm, n_hops):
+    def jasper_rerank_host(queries, frontier_ids, table, table_sqnorm,
+                           n_hops):
         if trace_counter is not None:
             trace_counter()
         s, q_n, l = frontier_ids.shape
@@ -379,4 +380,4 @@ def build_sharded_host_rerank_plan(rspec, *, axis_sizes: tuple,
             i = jnp.take_along_axis(i, pos, axis=-1)
         return i, d, jnp.max(n_hops, axis=0)
 
-    return rerank
+    return jasper_rerank_host

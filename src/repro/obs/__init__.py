@@ -4,10 +4,13 @@ Three layers, one import:
 
 - `MetricsRegistry` (metrics.py) — counters / gauges / fixed-bucket
   histograms plus adapters folding ServiceStats, PlanCache stats, and
-  per-shard gauges into a single namespaced `snapshot()` JSON dict.
-- `SpanTracer` / `span` (tracing.py) — thread-safe nestable host spans
-  exported as Chrome trace-event JSON (Perfetto-viewable). `obs.span()`
-  with no tracer installed is a shared no-op.
+  per-shard gauges into a single namespaced `snapshot()` JSON dict;
+  `registry()` is the process-wide one that holds the `session.*`
+  batch counters.
+- `span` (tracing.py) — nestable host spans that land in any
+  `jax.profiler` trace and, with a `SpanTracer` installed, in Chrome
+  trace-event JSON (Perfetto-viewable). With neither recording,
+  `obs.span()` is a shared no-op.
 - Per-search kernel telemetry rides the search path itself behind
   `SearchSpec(telemetry="on")` (see core/search_spec.py and
   docs/observability.md) — this package only consumes the resulting
@@ -25,6 +28,7 @@ from repro.obs.metrics import (
     MetricsRegistry,
     plain_json,
     plan_cache_collector,
+    registry,
     scheduler_stats_collector,
     service_stats_collector,
     shard_gauge_collector,
@@ -50,6 +54,7 @@ __all__ = [
     "get_tracer",
     "plain_json",
     "plan_cache_collector",
+    "registry",
     "scheduler_stats_collector",
     "service_stats_collector",
     "set_tracer",

@@ -12,8 +12,10 @@ unchanged (numpy scalars are coerced at the edge).
 Naming convention (docs/observability.md): dot-separated lowercase
 namespaces — `service.*` (ServiceStats), `plan_cache.*` (CacheStats),
 `shards.*` (per-shard gauges), `search.*` (instruments fed from kernel
-telemetry). Collectors run at snapshot time, so gauges like shard
-imbalance are always current, never stale copies.
+telemetry), `session.*` (the process-wide batch counters of
+`registry()`, folded into a service's snapshot as `process.session.*`).
+Collectors run at snapshot time, so gauges like shard imbalance are
+always current, never stale copies.
 """
 
 from __future__ import annotations
@@ -28,7 +30,7 @@ __all__ = [
     "SEARCH_LATENCY_BUCKETS_US", "HOPS_BUCKETS", "BEAM_OCCUPANCY_BUCKETS",
     "BATCH_OCCUPANCY_BUCKETS", "FETCH_LATENCY_BUCKETS_US",
     "service_stats_collector", "plan_cache_collector", "shard_gauge_collector",
-    "scheduler_stats_collector", "storage_stats_collector",
+    "scheduler_stats_collector", "storage_stats_collector", "registry",
 ]
 
 # Fixed bucket sets for the three paper-relevant distributions. Upper
@@ -227,6 +229,17 @@ class MetricsRegistry:
             for key, val in fn().items():
                 out[f"{ns}.{key}"] = plain_json(val)
         return out
+
+
+# The process-wide registry: the `session.*` counters recorded where
+# search batches are dispatched and landed (core/search_spec.py), which
+# every Searcher of the process shares
+_PROCESS = MetricsRegistry()
+
+
+def registry() -> MetricsRegistry:
+    """The process-wide `MetricsRegistry` (`session.*` counters)."""
+    return _PROCESS
 
 
 # ---------------------------------------------------------------------------

@@ -1,14 +1,22 @@
-"""Host-side span tracer exporting Chrome trace-event JSON.
+"""Program spans: one `obs.span` for the profiler trace and a JSON sink.
 
 The paper's serving claims — latency hiding in the fused search kernel,
 p99 flat through a consolidate + reshard cycle — are timing claims, and
-this module is the ONE place the repo measures host-side time: a
-thread-safe, nestable span tracer whose export is the Chrome trace-event
-format (`{"traceEvents": [...]}` of "ph": "X" complete events), so a
-churn run drops a file that opens directly in Perfetto
-(https://ui.perfetto.dev) or chrome://tracing.
+this module is the ONE place the repo names host-side time. Each
+`obs.span(name, **args)` goes to up to two sinks, with one start:
 
-Usage (docs/observability.md):
+* the profiler: under any `jax.profiler` session the span opens a
+  `jax.profiler.TraceAnnotation(name, **args)`, so it lands in the
+  session's `.xplane.pb` on the clock the device ops are converted to.
+  Perfetto or TensorBoard then shows the program's host spans above the
+  device ops they launched (docs/observability.md);
+* a `SpanTracer`, when one is installed: an in-memory recorder whose
+  export is the Chrome trace-event format (`{"traceEvents": [...]}` of
+  "ph": "X" complete events), for CPU-only use. Its timestamps are
+  wall-clock (`time.time_ns()`), the clock `TraceMe` stamps, so a span
+  starts at the same instant in both sinks.
+
+Usage:
 
     from repro import obs
     tracer = obs.SpanTracer()
@@ -18,18 +26,31 @@ Usage (docs/observability.md):
     tracer.export("trace.json")
 
 `obs.span(...)` is safe to leave in hot paths permanently: with no tracer
-installed it returns a shared no-op context manager — no allocation, no
-clock read, no lock (the zero-overhead off mode of the telemetry plane).
+installed and no profiler session it returns a shared no-op context
+manager after one global read and one `TraceAnnotation.is_enabled()`
+check — no allocation, no clock read, no lock.
 
 Span taxonomy (the names the serving/search stack emits — keep stable,
 dashboards key on them):
 
-    service.step            one scheduler tick (parent of the phases)
+    service.step            one update/serve tick (parent of the phases)
     service.delete / service.insert / service.search
     service.consolidate / service.rebalance
-    searcher.submit / searcher.drain
+    service.search_many     a pipelined run of search batches
+    service.tenant_search   one batch scoped to a tenant's label
+    service.serve           an open-loop trace replay (scheduler front end)
+    scheduler.flush         one coalesced batch dispatched
+    scheduler.harvest       one coalesced batch landed on the host
+    searcher.submit         prep + plan lookup + enqueue of one batch
+    searcher.drain          landing of the oldest in-flight batches
+    searcher.wait           blocked until one batch is ready on the device
+    searcher.land           device-to-host copies of one ready batch
     index.build             bulk construction (either driver)
     reshard.cores           shard-count-changing restore
+
+`searcher.wait` and `searcher.land` are emitted wherever a batch lands
+(`core.search_spec.land`): inside `searcher.drain`, `service.search`
+and `scheduler.harvest`.
 """
 
 from __future__ import annotations
@@ -41,6 +62,8 @@ import time
 from contextlib import contextmanager
 from typing import Any, Iterator
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["SpanTracer", "span", "use_tracer", "set_tracer", "get_tracer"]
 
 
@@ -48,35 +71,35 @@ class SpanTracer:
     """Thread-safe, nestable span recorder.
 
     Spans are recorded as Chrome trace "complete" events (ph "X"): wall
-    timestamp + duration in microseconds, pid = this process, tid = the
-    recording thread — nesting falls out of the format (Perfetto stacks
-    events on the same tid by time containment), so the tracer itself
-    keeps no explicit stack.
+    timestamp (`time.time_ns()`, the clock `TraceMe` stamps) + duration
+    (monotonic `perf_counter_ns`) in microseconds, pid = this process,
+    tid = the recording thread — nesting falls out of the format
+    (Perfetto stacks events on the same tid by time containment), so the
+    tracer itself keeps no explicit stack.
     """
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._events: list[dict] = []
-        # one origin for both clocks: wall time anchors the trace, the
-        # monotonic perf counter measures spans (immune to clock steps)
-        self._t0_wall_us = time.time() * 1e6
-        self._t0_perf = time.perf_counter()
 
     # ------------------------------------------------------------- recording
-    def _now_us(self) -> float:
-        return self._t0_wall_us + (time.perf_counter() - self._t0_perf) * 1e6
-
     @contextmanager
     def span(self, name: str, **args: Any) -> Iterator[None]:
-        """Record one span around the body. Nestable and thread-safe;
-        `args` land in the trace event's args dict (JSON-coerced)."""
-        start = self._now_us()
+        """Record one span around the body (and, under a profiler
+        session, the same span in the profiler trace). Nestable and
+        thread-safe; `args` land in the trace event's args dict
+        (JSON-coerced)."""
+        # the start on TraceMe's clock; the duration on the monotonic
+        # perf counter, immune to wall-clock steps
+        start = time.time_ns()
+        t0 = time.perf_counter_ns()
         try:
-            yield
+            with _annotation(name, args):
+                yield
         finally:
-            end = self._now_us()
-            evt = {"name": name, "ph": "X", "ts": start,
-                   "dur": end - start, "pid": os.getpid(),
+            dur = time.perf_counter_ns() - t0
+            evt = {"name": name, "ph": "X", "ts": start / 1e3,
+                   "dur": dur / 1e3, "pid": os.getpid(),
                    "tid": threading.get_ident()}
             if args:
                 evt["args"] = {k: _jsonable(v) for k, v in args.items()}
@@ -142,8 +165,8 @@ _active: SpanTracer | None = None
 
 class _NoopSpan:
     """Shared reusable no-op context manager: `obs.span()` with tracing
-    disabled costs one global read and returns this singleton — no
-    allocation, no clock, no lock."""
+    disabled costs one global read and one `is_enabled()` check and
+    returns this singleton — no allocation, no clock, no lock."""
 
     __slots__ = ()
 
@@ -155,6 +178,12 @@ class _NoopSpan:
 
 
 _NOOP = _NoopSpan()
+
+def _annotation(name: str, args: dict):
+    """The span's profiler-trace half: a `TraceAnnotation` while a
+    profiler session records host events, else the no-op."""
+    return TraceAnnotation(name, **args) if TraceAnnotation.is_enabled() \
+        else _NOOP
 
 
 def set_tracer(tracer: SpanTracer | None) -> SpanTracer | None:
@@ -180,8 +209,10 @@ def use_tracer(tracer: SpanTracer) -> Iterator[SpanTracer]:
 
 
 def span(name: str, **args: Any):
-    """Span against the active tracer; a shared no-op when none is set."""
+    """One program span: into the active tracer (if any) and, under a
+    profiler session, into the profiler trace; a shared no-op when
+    neither records."""
     t = _active
     if t is None:
-        return _NOOP
+        return _annotation(name, args)
     return t.span(name, **args)

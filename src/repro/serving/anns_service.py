@@ -60,6 +60,7 @@ from repro.core.search_spec import (
     SearchSpec,
     check_quantized_backend,
     check_rows_tier,
+    land,
 )
 from repro.obs.tracing import span as obs_span
 
@@ -250,17 +251,21 @@ class AnnsService:
         """The service's unified metrics plane (lazily created).
 
         One `MetricsRegistry` folding ServiceStats (`service.*`), the
-        index's plan-cache counters (`plan_cache.*`), and per-shard
-        live/imbalance gauges (`shards.*`) as snapshot-time collectors,
-        plus the search histograms (`search.latency_us`, `search.hops`,
-        `search.beam_occupancy` — occupancy fills only when the served
-        spec has telemetry="on"). Never touching this method keeps the
-        serve loop metrics-free: histograms observe only once the
-        registry exists.
+        index's plan-cache counters (`plan_cache.*`), per-shard
+        live/imbalance gauges (`shards.*`) and the batch counters of
+        `obs.registry()` (`process.session.*`: process-wide, so they
+        count every searcher's batches, not this service's alone) as
+        snapshot-time collectors, plus the search histograms (`search.latency_us`,
+        `search.hops`, `search.beam_occupancy` — occupancy fills only
+        when the served spec has telemetry="on"). Never touching this
+        method keeps the serve loop histogram-free: histograms observe
+        only once the registry exists.
         """
         if self._metrics is None:
             from repro.obs import metrics as obs_metrics
             reg = obs_metrics.MetricsRegistry()
+            reg.register_collector("process",
+                                   obs_metrics.registry().snapshot)
             reg.register_collector(
                 "service", obs_metrics.service_stats_collector(self))
             reg.register_collector(
@@ -330,10 +335,9 @@ class AnnsService:
         return n
 
     def _finish(self, res: SearchResult) -> SearchTicket:
-        """Host-land a search result: verify the serving contract, fold
-        the hop counts into the stats, stamp the ticket."""
-        ids = np.asarray(res.ids)
-        n_hops = np.asarray(res.n_hops)
+        """Take a host-landed result (`land`): verify the serving
+        contract, fold the hop counts into the stats, stamp the ticket."""
+        ids, n_hops, tel = res.ids, res.n_hops, res.telemetry
         if self.verify:
             # O(Q*k): gather only the returned ids' tombstone bits — the
             # full bitmap never unpacks on the serving path (the drivers'
@@ -351,9 +355,6 @@ class AnnsService:
         self.stats.last_mean_hops = float(n_hops.mean()) if n_hops.size \
             else 0.0
         self._stamp()
-        tel = res.telemetry
-        if tel is not None:
-            tel = type(tel)(*(np.asarray(t) for t in tel))
         if self._metrics is not None:
             self._hops_hist.observe_many(n_hops.tolist())
             if tel is not None:
@@ -361,9 +362,7 @@ class AnnsService:
                 # hops a row never ran stay 0 in the log — only real
                 # per-hop occupancies feed the histogram
                 self._occ_hist.observe_many(occ[occ > 0].tolist())
-        return SearchTicket(ids=ids, dists=np.asarray(res.dists),
-                            n_hops=n_hops, generation=res.generation,
-                            telemetry=tel, estimated=res.estimated)
+        return res
 
     def search(self, queries, k: int | None = None, **kw) -> SearchTicket:
         """Serve one search batch at the current snapshot generation.
@@ -381,7 +380,8 @@ class AnnsService:
                 DeprecationWarning, stacklevel=2)
         with obs_span("service.search"):
             t0 = time.perf_counter()
-            ticket = self._finish(self.searcher(k, **kw).search(queries))
+            ticket = self._finish(land(self.searcher(k, **kw)
+                                       .search(queries)))
             if self._metrics is not None:
                 self._lat_hist.observe((time.perf_counter() - t0) * 1e6)
         return ticket
@@ -399,10 +399,12 @@ class AnnsService:
         every ticket carries the same snapshot generation."""
         ses = self.searcher(k)
         tickets: list[SearchTicket] = []
-        for q in query_batches:
-            if ses.submit(q) >= self.MAX_INFLIGHT:
-                tickets += [self._finish(r) for r in ses.drain(1)]
-        return tickets + [self._finish(r) for r in ses.drain()]
+        with obs_span("service.search_many"):
+            for q in query_batches:
+                if ses.submit(q) >= self.MAX_INFLIGHT:
+                    tickets += [self._finish(r) for r in ses.drain(1)]
+            tickets += [self._finish(r) for r in ses.drain()]
+        return tickets
 
     # ------------------------------------------------------ tenant namespaces
     def register_tenant(self, name: str, *,
@@ -499,7 +501,7 @@ class AnnsService:
             self._tenant_searchers[key] = ses
         with obs_span("service.tenant_search", tenant=name):
             t0 = time.perf_counter()
-            ticket = self._finish(ses.search(queries))
+            ticket = self._finish(land(ses.search(queries)))
             if self._metrics is not None:
                 self._lat_hist.observe((time.perf_counter() - t0) * 1e6)
         if self.verify:
@@ -562,16 +564,19 @@ class AnnsService:
         realtime: honor arrival times (open loop: submission never waits
                   for completions — while the next arrival is in the
                   future the loop keeps polling, so harvest/dispatch
-                  overlap admission). False = saturation replay: every
+                  overlap admission). Each handle is stamped with its
+                  due time (`t_due`, the arrival's `at` on the clock),
+                  so the report times latency from it and gives the
+                  loop's lateness. False = saturation replay: every
                   arrival is admitted as fast as the queue bound allows
-                  (the offered-load -> infinity limit).
+                  (the offered-load -> infinity limit; no due times).
 
         Returns `(report, handles)`: an open-loop serving report (QPS,
-        p50/p99 latency, SLO hit rate, flush-reason breakdown, batch
-        occupancy — the BENCH_serving.json record shape) and the
-        per-query handles. Completed queries fold into `ServiceStats`
-        and the serving contract (no tombstoned ids, ever) is verified
-        over every returned ticket when `verify=True`.
+        p50/p99 latency, SLO hit rate, generator lateness, flush-reason
+        breakdown, batch occupancy — the BENCH_serving.json record
+        shape) and the per-query handles. Completed queries fold into
+        `ServiceStats` and the serving contract (no tombstoned ids,
+        ever) is verified over every returned ticket when `verify=True`.
         """
         import time as _time
 
@@ -589,7 +594,8 @@ class AnnsService:
                         sched.poll()       # overlap: harvest + dispatch
                 handles.append(sched.submit(
                     queries[a.query_id], lane=a.lane,
-                    slo_budget_s=a.slo_budget_s))
+                    slo_budget_s=a.slo_budget_s,
+                    t_due=t0 + a.at if realtime else None))
                 sched.poll()
             sched.drain()
         wall = clk() - t0

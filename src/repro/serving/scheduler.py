@@ -61,6 +61,7 @@ from repro.core.search_spec import (
     BUCKET_LADDER,
     SearchResult,
     SearchSpec,
+    land,
     pad_to_bucket,
 )
 from repro.obs.tracing import span as obs_span
@@ -117,10 +118,15 @@ class SchedulerConfig:
 class QueryHandle:
     """One standing query's lifecycle: queued -> inflight -> done (or
     rejected at admission). Carries its own slice of the coalesced
-    batch's result — padding rows are never visible here."""
+    batch's result — padding rows are never visible here.
+
+    `t_due` is when an open-loop generator meant to send the query
+    (`AnnsService.serve` stamps the arrival's time on the scheduler's
+    clock); None where nobody set one. `latency_s` and `slo_met`, and so
+    `stats.slo_misses` and `summarize_handles`, count from it where set."""
 
     __slots__ = ("query", "lane", "slo_budget_s", "status",
-                 "t_submit", "t_dispatch", "t_done",
+                 "t_due", "t_submit", "t_dispatch", "t_done",
                  "ids", "dists", "n_hops", "generation", "estimated")
 
     def __init__(self, query, lane: str, slo_budget_s: float,
@@ -129,6 +135,7 @@ class QueryHandle:
         self.lane = lane
         self.slo_budget_s = slo_budget_s
         self.status = status
+        self.t_due: float | None = None
         self.t_submit = t_submit
         self.t_dispatch: float | None = None
         self.t_done: float | None = None
@@ -138,10 +145,13 @@ class QueryHandle:
 
     @property
     def latency_s(self) -> float | None:
-        """Queue + execution latency (submission to host-landed result)."""
+        """Queue + execution latency: from the due time where one was
+        stamped (so a stalled generator's wait counts), else from
+        submission, to the host-landed result."""
         if self.t_done is None:
             return None
-        return self.t_done - self.t_submit
+        return self.t_done - (self.t_submit if self.t_due is None
+                              else self.t_due)
 
     @property
     def slo_met(self) -> bool | None:
@@ -211,12 +221,7 @@ class _AsyncBatch:
 
     def take(self) -> SearchResult:
         """Host-land the result (blocks on the device transfer)."""
-        r = self._res
-        return SearchResult(ids=np.asarray(r.ids),
-                            dists=np.asarray(r.dists),
-                            n_hops=np.asarray(r.n_hops),
-                            generation=r.generation,
-                            estimated=r.estimated)
+        return land(self._res)
 
 
 class _Lane:
@@ -307,21 +312,26 @@ class StandingQueryScheduler:
 
     # --------------------------------------------------------- admission
     def submit(self, query, *, lane: str = "default",
-               slo_budget_s: float | None = None) -> QueryHandle:
+               slo_budget_s: float | None = None,
+               t_due: float | None = None) -> QueryHandle:
         """Admit one standing query (or shed it: a full queue returns a
         `rejected` handle immediately — backpressure, never unbounded
-        growth). Returns the query's lifecycle handle."""
+        growth). Returns the query's lifecycle handle, stamped with
+        `t_due` (when a generator meant to send it) where given."""
         ln = self._lanes[lane]
         budget = self.config.slo_budget_s if slo_budget_s is None \
             else float(slo_budget_s)
         now = self.clock()
         if self.queue_depth >= self.config.max_queue:
             self.stats.rejected += 1
-            return QueryHandle(None, lane, budget, now, status=REJECTED)
+            h = QueryHandle(None, lane, budget, now, status=REJECTED)
+            h.t_due = t_due
+            return h
         q = np.asarray(query)
         if q.ndim == 2 and q.shape[0] == 1:
             q = q[0]                      # accept a (1, D) singleton batch
         h = QueryHandle(q, lane, budget, now)
+        h.t_due = t_due
         ln.queue.append(h)
         self.stats.submitted += 1
         return h
@@ -484,14 +494,19 @@ class StandingQueryScheduler:
 
 def summarize_handles(handles, wall_s: float) -> dict:
     """Open-loop serving report over a set of query handles: completed /
-    rejected counts, achieved QPS, latency percentiles (ms), SLO hit
-    rate. Plain-JSON (BENCH_serving.json records these directly)."""
+    rejected counts, achieved QPS, latency percentiles (ms) and SLO hit
+    rate, both timed from each query's due time where one was stamped
+    (so a stalled generator's wait counts), and the generator's lateness
+    (submission - due time, ms; None without due times). Plain-JSON
+    (BENCH_serving.json records these directly)."""
     done = [h for h in handles if h.status == DONE]
     lat_ms = np.asarray(sorted(h.latency_s * 1e3 for h in done)) \
         if done else np.zeros((0,))
     pct = (lambda p: float(np.percentile(lat_ms, p))) if done \
         else (lambda p: None)
     met = sum(1 for h in done if h.slo_met)
+    late_ms = np.asarray([(h.t_submit - h.t_due) * 1e3 for h in handles
+                          if h.t_due is not None])
     return {
         "n": len(handles),
         "completed": len(done),
@@ -502,4 +517,7 @@ def summarize_handles(handles, wall_s: float) -> dict:
         "mean_ms": float(lat_ms.mean()) if done else None,
         "max_ms": float(lat_ms.max()) if done else None,
         "slo_hit_rate": met / len(done) if done else None,
+        "lateness_p99_ms": (float(np.percentile(late_ms, 99))
+                            if late_ms.size else None),
+        "lateness_max_ms": float(late_ms.max()) if late_ms.size else None,
     }

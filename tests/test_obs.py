@@ -18,6 +18,11 @@ The contracts under test:
   * `ServiceStats` / `CacheStats` / `MetricsRegistry` snapshots are
     plain JSON (round-trip through `json.dumps`), with guarded derived
     rates (no ZeroDivisionError on empty stats).
+  * program spans land in a `jax.profiler` trace beside the search
+    program `jit_jasper_search`, starting where the `SpanTracer` says;
+    the `session.*` counters count what the landed batches hold; the
+    named scopes, spans and counters leave every search bit-identical
+    and every plan-cache key unchanged.
 """
 
 import json
@@ -425,3 +430,216 @@ def test_service_unified_snapshot_and_spans():
     assert snap["search.latency_us"]["count"] == 1
     assert snap["search.hops"]["count"] == 4
     assert snap["service.n_deletes"] == 1
+
+
+# ------------------------------------- profiler spans and session counters
+def _session_delta(before: dict) -> dict:
+    from repro import obs
+    now = obs.registry().snapshot()
+    return {k[len("session."):]: v - before.get(k, 0)
+            for k, v in now.items() if k.startswith("session.")}
+
+
+def _warm_service(idx, queries, **spec):
+    from repro.serving.anns_service import AnnsService
+    svc = AnnsService(idx, spec=SearchSpec(k=K, beam_width=BEAM,
+                                           quantized=True, **spec))
+    svc.search_many([queries, queries])      # trace + compile here
+    return svc
+
+
+def _xplane_spans(path: str, names: set) -> tuple[dict, set]:
+    """({span name: sorted absolute start ns}, hlo_module names) of a
+    profiler trace."""
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(path)
+    base, starts, modules = None, {}, set()
+    for plane in pd.planes:
+        stats = dict(plane.stats)
+        if "profile_start_time" in stats:
+            base = stats["profile_start_time"]
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    starts.setdefault(ev.name, []).append(ev.start_ns)
+                for k, v in ev.stats:
+                    if k == "hlo_module":
+                        modules.add(v)
+    assert base is not None, "trace holds no profile_start_time"
+    return ({n: sorted(base + t for t in ts) for n, ts in starts.items()},
+            modules)
+
+
+def test_profiler_trace_holds_program_spans(built, tmp_path):
+    """A CPU profiler trace of a small search_many holds the program's
+    spans and the `jit_jasper_search` program, and each span starts
+    within 1 ms of the same span's SpanTracer record."""
+    import glob
+
+    import jax
+
+    from repro.obs.tracing import SpanTracer, use_tracer
+
+    idx, queries = built
+    svc = _warm_service(idx, queries)
+    names = {"service.search_many", "searcher.submit", "searcher.wait",
+             "searcher.land"}
+    tr = SpanTracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with use_tracer(tr):
+            svc.search_many([queries] * 3)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[0]
+    starts, modules = _xplane_spans(path, names)
+    assert "jit_jasper_search" in modules, sorted(modules)
+    mine = {}
+    for e in tr.events():
+        mine.setdefault(e["name"], []).append(e["ts"] * 1e3)
+    for name in names:
+        assert len(starts.get(name, [])) == len(mine[name]), name
+        for a, b in zip(starts[name], sorted(mine[name])):
+            assert abs(a - b) < 1e6, (name, a - b)
+    assert len(mine["searcher.submit"]) == 3
+    assert len(mine["service.search_many"]) == 1
+
+
+def test_span_sinks_off_and_on(tmp_path):
+    """No tracer and no profiler: the shared no-op. Under a profiler
+    session alone, a TraceAnnotation. SpanTracer stamps wall-clock ns."""
+    import time
+
+    import jax
+
+    from repro.obs import tracing
+
+    assert tracing.span("x", a=1) is tracing._NOOP
+    tr = tracing.SpanTracer()
+    t0 = time.time_ns()
+    with tracing.use_tracer(tr):
+        with tracing.span("y"):
+            pass
+    t1 = time.time_ns()
+    (evt,) = tr.events()
+    assert t0 / 1e3 <= evt["ts"] <= evt["ts"] + evt["dur"] <= t1 / 1e3
+    with jax.profiler.trace(str(tmp_path)):
+        assert isinstance(tracing.span("z"), jax.profiler.TraceAnnotation)
+
+
+def test_session_counters_count_landed_batches(built):
+    """After N batches: session.batches == N, hops == sum of n_hops,
+    trips == sum of each batch's max n_hops; every dispatch that did not
+    trace is timed, and one that traced is left out of the dispatch
+    counters (the plan cache counts its trace)."""
+    from repro import obs
+
+    idx, queries = built
+    svc = _warm_service(idx, queries)
+    before = obs.registry().snapshot()
+    tickets = svc.search_many([queries, queries[::-1], queries * 2])
+    d = _session_delta(before)
+    assert d["batches"] == 3 and d["rows"] == 3 * Q
+    assert d["hops"] == sum(int(t.n_hops.sum()) for t in tickets)
+    assert d["trips"] == sum(int(t.n_hops.max()) for t in tickets)
+    assert d["dispatches"] == 3 and d["dispatch_s"] > 0
+    assert d["wait_s"] >= 0 and d["land_s"] > 0
+    # a new query shape traces: the plan cache counts it, and the
+    # dispatch counters (host time without compiles) leave it out
+    before = obs.registry().snapshot()
+    traces = idx.plans.stats.traces
+    svc.search_many([queries[:3]])
+    d = _session_delta(before)
+    assert idx.plans.stats.traces == traces + 1
+    assert d["batches"] == 1
+    assert d.get("dispatches", 0) == 0 and d.get("dispatch_s", 0) == 0
+    # the service's snapshot folds the process-wide registry in, labelled
+    # as process-wide
+    snap = svc.metrics_snapshot()
+    assert "session.batches" not in snap
+    assert snap["process.session.batches"] == obs.registry().snapshot()[
+        "session.batches"]
+
+
+def test_trips_is_the_while_loop_trip_count(built):
+    """On the unfused while_loop at expand=1 the batch's max n_hops is
+    the loop's trip count: the visited log is filled exactly up to it."""
+    idx, queries = built
+    from repro.core.beam_search import beam_search_quantized
+    from repro.core.rabitq import rabitq_preprocess_query
+
+    core = idx.core
+    rq = rabitq_preprocess_query(core.rq_params, jnp.asarray(queries))
+    res = beam_search_quantized(idx.graph, core.codes, rq,
+                                beam_width=BEAM, max_iters=64)
+    vlog = np.asarray(res.visited_ids)
+    trips = int(np.asarray(res.n_hops).max())
+    assert (vlog[:, :trips] >= 0).any(axis=0).all()
+    assert (vlog[:, trips:] == -1).all()
+
+
+@pytest.mark.parametrize("quantized,kernels,fusion", GRID)
+def test_scopes_and_counters_bitwise_identity(built, monkeypatch,
+                                              quantized, kernels, fusion):
+    """The search program with named scopes, spans and counters in place
+    gives bit-identical ids, dists and n_hops to the same program traced
+    with every named scope taken out; a second search with a tracer
+    installed adds no plan-cache entry and no trace."""
+    import contextlib
+
+    import jax
+
+    from repro.core.index_core import core_search
+    from repro.obs.tracing import SpanTracer, use_tracer
+
+    idx, queries = built
+    ses = idx.searcher(_spec(quantized, kernels, fusion))
+    res = ses.search(queries)
+    entries, traces = len(idx.plans), idx.plans.stats.traces
+    with use_tracer(SpanTracer()):
+        res2 = ses.search(queries)
+    assert len(idx.plans) == entries
+    assert idx.plans.stats.traces == traces
+    assert np.array_equal(np.asarray(res.ids), np.asarray(res2.ids))
+
+    def program():
+        # a fresh function each time: jit caches traces per function
+        def bare(core, q):
+            return core_search.__wrapped__(core, q, spec=ses.resolved,
+                                           filter_tombstones=False)
+        return jax.jit(bare)
+    q = idx._prep_query(queries)
+    scoped_text = program().lower(idx.core, q).as_text(debug_info=True)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = program()
+    plain_text = plain.lower(idx.core, q).as_text(debug_info=True)
+    assert "hop.select" in scoped_text or fusion != "none"
+    assert "search.finalize" in scoped_text
+    assert "search.finalize" not in plain_text and "hop." not in plain_text
+    ids, dists, n_hops = plain(idx.core, q)[:3]
+    assert np.array_equal(np.asarray(res.ids), np.asarray(ids))
+    assert np.array_equal(np.asarray(res.dists).view(np.int32),
+                          np.asarray(dists).view(np.int32))
+    assert np.array_equal(np.asarray(res.n_hops), np.asarray(n_hops))
+
+
+def test_scheduler_landing_carries_telemetry(built):
+    """The scheduler's harvest lands through the one landing helper, so
+    a telemetry-on batch keeps its counters (they were dropped before)."""
+    from repro.serving.scheduler import _AsyncBatch
+
+    idx, queries = built
+    ses = idx.searcher(_spec(True, False, "none", telemetry="on"))
+    got = _AsyncBatch(ses.search(queries)).take()
+    assert got.telemetry is not None
+    assert all(isinstance(t, np.ndarray) for t in got.telemetry)
+    assert isinstance(got.ids, np.ndarray)
+    want = ses.search(queries).telemetry
+    for a, b in zip(got.telemetry, want):
+        assert np.array_equal(a, np.asarray(b))
+

@@ -452,6 +452,64 @@ def test_serve_folds_service_stats_and_metrics(built):
         for r in ("full", "deadline", "idle", "drain"))
 
 
+def test_summarize_handles_times_from_due():
+    """Latency and SLO count from the due time where one is stamped (on
+    the handle itself, so the summary agrees with it), and the
+    generator's lateness is reported; handles without a due time keep
+    their submit-based values."""
+    from repro.serving.scheduler import QueryHandle
+
+    def handle(t_due, t_submit, t_done):
+        h = QueryHandle(None, "default", 0.010, t_submit, status="done")
+        h.t_due, h.t_done = t_due, t_done
+        return h
+
+    plain = summarize_handles([handle(None, 1.0, 1.005)], wall_s=1.0)
+    assert plain["p50_ms"] == pytest.approx(5.0)
+    assert plain["slo_hit_rate"] == 1.0
+    assert plain["lateness_p99_ms"] is None
+    late = handle(0.990, 1.0, 1.005)
+    # one definition: the handle's own latency and SLO count from t_due
+    assert late.latency_s == pytest.approx(0.015)
+    assert late.slo_met is False
+    due = summarize_handles([late, handle(1.000, 1.0, 1.002)], wall_s=1.0)
+    assert due["max_ms"] == pytest.approx(15.0)
+    assert due["slo_hit_rate"] == 0.5          # 15 ms > the 10 ms budget
+    assert due["lateness_max_ms"] == pytest.approx(10.0)
+    assert due["lateness_p99_ms"] == pytest.approx(9.9)
+
+
+def test_serve_in_real_time_stamps_due_times(built):
+    """A real-time replay stamps each handle with the arrival's due time
+    on the loop's clock; the loop submits at or after it, and the report
+    gives the lateness."""
+    idx, _ = built
+    rng = np.random.default_rng(9)
+    pool = rng.normal(size=(16, DIMS)).astype(np.float32)
+    svc = AnnsService(idx, spec=SearchSpec(k=5, beam_width=16,
+                                           quantized=True))
+
+    class StepClock(FakeClock):
+        def __call__(self) -> float:
+            self.t += 1e-4                        # every read moves on
+            return self.t
+
+    trace = poisson_trace(2000.0, 20, n_queries=16, seed=4)
+    clk = StepClock()
+    rep, handles = svc.serve(trace, pool, buckets=(1, 8), clock=clk)
+    t0 = handles[0].t_due - trace[0].at
+    assert [h.t_due for h in handles] == pytest.approx(
+        [t0 + a.at for a in trace])
+    assert all(h.t_submit >= h.t_due for h in handles)
+    assert rep["lateness_max_ms"] == pytest.approx(
+        max(h.t_submit - h.t_due for h in handles) * 1e3)
+    assert rep["lateness_p99_ms"] <= rep["lateness_max_ms"]
+    # a saturation replay has no due times
+    rep, handles = svc.serve(trace, pool, buckets=(1, 8), realtime=False)
+    assert all(h.t_due is None for h in handles)
+    assert rep["lateness_max_ms"] is None
+
+
 def test_rejected_handles_carry_no_query_payload(built):
     idx, queries = built
     sched = StandingQueryScheduler(
