@@ -29,7 +29,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.core.beam_search import beam_search, make_exact_scorer
+from repro.core.beam_search import (_f32_total_key, beam_search,
+                                     make_exact_scorer)
 from repro.core.robust_prune import robust_prune_batch
 from repro.core.vamana import VamanaGraph
 from repro.core.medoid import compute_medoid
@@ -83,8 +84,7 @@ def _adjacency_distances(vectors: Array, pivot_ids: Array, adj_rows: Array,
 def _f32_order_key(x: Array) -> Array:
     """int32 whose signed order is x's float order (-0.0 folded into 0.0;
     x holds no NaN)."""
-    b = jax.lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x), jnp.int32)
-    return b ^ ((b >> 31) & 0x7FFFFFFF)
+    return _f32_total_key(jnp.where(x == 0.0, 0.0, x))
 
 
 def _group_reverse_edges(dst: Array, src: Array, dist: Array, rev_cap: int

@@ -6,7 +6,8 @@ described v5e:2x2 topology and compiles it with the TPU compiler: nothing
 runs, so these tests pass on a CPU-only machine. What Mosaic refuses
 (slices off the lane tiling, primitives it cannot lower, loads from HBM
 refs) fails here, not on the chip. Each compiled program must hold the
-kernel as a `tpu_custom_call`.
+kernel as a `tpu_custom_call`. The unfused quantized search compiles here
+too, and its frontier merge (`hop.merge`) must hold no gather.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -17,12 +18,14 @@ compile for a described chip cannot be read back without one).
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.beam_search import beam_search_quantized
 from repro.core.rabitq import RaBitQCodes, RaBitQQuery
 from repro.core.vamana import VamanaGraph
 from repro.kernels.distance.ops import gather_l2_chunked, pairwise_l2
@@ -167,3 +170,26 @@ def test_topk_compiles(spec, k):
 
     args = (spec((Q, L + R), jnp.float32), spec((Q, L + R), jnp.int32))
     assert "tpu_custom_call" in compile_text(run, *args)
+
+
+@pytest.mark.parametrize("d", DIMS)
+def test_unfused_search_merge_holds_no_gather(spec, d):
+    """The default lane (jnp quantized, unfused): the hop's top-L merge
+    carries ids and visited bits through its sort, so no gather in the
+    compiled program carries `hop.merge` in its op_name; the code gathers
+    of `hop.score` do."""
+    def run(adj, nv, med, packed, dadd, drs, qrot, qadd, qsum):
+        r = beam_search_quantized(
+            VamanaGraph(adj, nv, med), RaBitQCodes(packed, dadd, drs, BITS, d),
+            RaBitQQuery(qrot, qadd, qsum), beam_width=L, max_iters=96)
+        return r.frontier_ids, r.frontier_dists, r.n_hops
+
+    args = (spec((CAP, R), jnp.int32), spec((), jnp.int32),
+            spec((), jnp.int32), spec((CAP, packed_width(d)), jnp.uint8),
+            spec((CAP,), jnp.float32), spec((CAP,), jnp.float32),
+            spec((Q, d), jnp.float32), spec((Q,), jnp.float32),
+            spec((Q,), jnp.float32))
+    gathers = [ln for ln in compile_text(run, *args).splitlines()
+               if re.search(r"=\s.*\bgather\(", ln)]
+    assert any("hop.score" in ln for ln in gathers)
+    assert not any("hop.merge" in ln for ln in gathers)
