@@ -39,9 +39,24 @@ Array = jax.Array
 
 _INF = float("inf")
 
-# the largest insert batch: one 65,536-row `batch_insert_at` over 10^6 rows
-# of 128-d f32 holds about 9.7 GB of temporaries on a TPU v5e (16 GB)
-MAX_BATCH = 1 << 16
+# One `batch_insert_at` of B rows into a table of N rows of D floats holds
+# about 160 x B x (D + 800) bytes of temporaries, beside about 9 x N x D
+# bytes of rows and their working copies (XLA's memory analysis of the
+# rung compiled for a TPU v5e, which gives a program 15.75 GB): 9.7 GB of
+# temporaries at B = 65,536, N = 10^6, D = 128; 9.2 GB at B = 32,768,
+# N = 65,536, D = 960; and 16.3 GB in all at B = 32,768, N = 10^6,
+# D = 960, which does not fit, against 13.4 GB at B = 16,384.
+_CHIP_BYTES = 15.75e9
+
+
+def max_batch(n_rows: int, dims: int) -> int:
+    """The largest insert batch that fits one chip beside a table of
+    `n_rows` x `dims` floats: a power of two, at most 65,536 (65,536 at
+    D = 128 for any N up to 10^6; at D = 960, 32,768 up to 262,144 rows
+    and 16,384 at 10^6)."""
+    free = _CHIP_BYTES - 9 * n_rows * dims
+    b = max(256, int(free // (160 * (dims + 800))))
+    return min(1 << 16, 1 << (b.bit_length() - 1))
 
 
 @dataclass(frozen=True)
@@ -174,45 +189,52 @@ def batch_insert_at(vectors: Array, graph: VamanaGraph, new_ids: Array,
         from repro.core.mutations import unpack_bitmap  # lazy: no cycle
         live = ~unpack_bitmap(tombstone_bits, adj.shape[0])
 
+    # each step under a named scope (`build.search`, `build.prune`,
+    # `build.reverse`), so a device trace of a build splits by step
     # ---- Step 1: snapshot beam search ------------------------------------
-    score = make_exact_scorer(vectors, queries, n_old, vec_sqnorm)
-    res = beam_search(graph, score, batch_size,
-                      beam_width=params.beam_width, max_iters=params.max_iters)
-
-    # candidate edges: visited set ∪ final frontier (paper: both returned)
-    cand_ids = jnp.concatenate([res.visited_ids, res.frontier_ids], axis=1)
-    cand_dists = jnp.concatenate([res.visited_dists, res.frontier_dists], axis=1)
+    with jax.named_scope("build.search"):
+        score = make_exact_scorer(vectors, queries, n_old, vec_sqnorm)
+        res = beam_search(graph, score, batch_size,
+                          beam_width=params.beam_width,
+                          max_iters=params.max_iters)
+        # candidate edges: visited set ∪ final frontier (both returned)
+        cand_ids = jnp.concatenate([res.visited_ids, res.frontier_ids],
+                                   axis=1)
+        cand_dists = jnp.concatenate([res.visited_dists, res.frontier_dists],
+                                     axis=1)
 
     # ---- Step 2: forward prune -------------------------------------------
-    fwd = robust_prune_batch(vectors, new_ids, cand_ids, cand_dists, n_old,
-                             degree_bound=r, alpha=params.alpha,
-                             chunk_size=params.prune_chunk, live=live)
-    adj = adj.at[new_ids].set(fwd.selected_ids)
+    with jax.named_scope("build.prune"):
+        fwd = robust_prune_batch(vectors, new_ids, cand_ids, cand_dists,
+                                 n_old, degree_bound=r, alpha=params.alpha,
+                                 chunk_size=params.prune_chunk, live=live)
+        adj = adj.at[new_ids].set(fwd.selected_ids)
 
     # ---- Step 3: reverse edges (full sort + batched prune) ----------------
-    dst = fwd.selected_ids.reshape(-1)                     # (B*R,)
-    src = jnp.repeat(new_ids, r)
-    dist = fwd.selected_dists.reshape(-1)
-    touched, in_ids, in_dists = _group_reverse_edges(dst, src, dist,
-                                                     params.rev_cap)
+    with jax.named_scope("build.reverse"):
+        dst = fwd.selected_ids.reshape(-1)                     # (B*R,)
+        src = jnp.repeat(new_ids, r)
+        dist = fwd.selected_dists.reshape(-1)
+        touched, in_ids, in_dists = _group_reverse_edges(dst, src, dist,
+                                                         params.rev_cap)
 
-    exist_rows = adj[jnp.maximum(touched, 0)]              # (T, R)
-    exist_rows = jnp.where((touched >= 0)[:, None], exist_rows, -1)
-    exist_dists = _adjacency_distances(vectors, touched, exist_rows,
-                                       params.prune_chunk)
+        exist_rows = adj[jnp.maximum(touched, 0)]              # (T, R)
+        exist_rows = jnp.where((touched >= 0)[:, None], exist_rows, -1)
+        exist_dists = _adjacency_distances(vectors, touched, exist_rows,
+                                           params.prune_chunk)
 
-    # high-water mark: contiguous batches advance by B; slot-reusing batches
-    # advance only past the largest fresh tail id
-    n_after = (n_old if already_inserted
-               else jnp.maximum(n_old, jnp.max(new_ids) + 1))
-    cand2_ids = jnp.concatenate([exist_rows, in_ids], axis=1)
-    cand2_dists = jnp.concatenate([exist_dists, in_dists], axis=1)
-    rev = robust_prune_batch(vectors, touched, cand2_ids, cand2_dists,
-                             n_after.astype(jnp.int32), degree_bound=r,
-                             alpha=params.alpha, chunk_size=params.prune_chunk,
-                             live=live)
-    adj = adj.at[jnp.where(touched >= 0, touched, adj.shape[0])].set(
-        rev.selected_ids, mode="drop")
+        # high-water mark: contiguous batches advance by B; slot-reusing
+        # batches advance only past the largest fresh tail id
+        n_after = (n_old if already_inserted
+                   else jnp.maximum(n_old, jnp.max(new_ids) + 1))
+        cand2_ids = jnp.concatenate([exist_rows, in_ids], axis=1)
+        cand2_dists = jnp.concatenate([exist_dists, in_dists], axis=1)
+        rev = robust_prune_batch(vectors, touched, cand2_ids, cand2_dists,
+                                 n_after.astype(jnp.int32), degree_bound=r,
+                                 alpha=params.alpha,
+                                 chunk_size=params.prune_chunk, live=live)
+        adj = adj.at[jnp.where(touched >= 0, touched, adj.shape[0])].set(
+            rev.selected_ids, mode="drop")
 
     return VamanaGraph(adjacency=adj, n_valid=n_after.astype(jnp.int32),
                        medoid=graph.medoid)
@@ -231,7 +253,10 @@ def bootstrap_graph(vectors: Array, graph: VamanaGraph, *, n0: int,
     ids = jnp.arange(n0, dtype=jnp.int32)
     v = vectors[:n0].astype(jnp.float32)
     sq = jnp.sum(v * v, axis=-1)
-    d = jnp.maximum(sq[:, None] - 2.0 * (v @ v.T) + sq[None, :], 0.0)
+    # float32 rows: at default precision a TPU runs this product as one
+    # bfloat16 pass, which ranks float rows' candidates below float32
+    vv = jnp.matmul(v, v.T, precision=jax.lax.Precision.HIGHEST)
+    d = jnp.maximum(sq[:, None] - 2.0 * vv + sq[None, :], 0.0)
     c = min(4 * r, n0)
     sd, si = jax.lax.top_k(-d, c)                           # nearest c
     cand_ids = si.astype(jnp.int32)
@@ -246,8 +271,7 @@ def bootstrap_graph(vectors: Array, graph: VamanaGraph, *, n0: int,
 
 def build_graph(vectors: Array, n_total: int, *, params: ConstructionParams,
                 bootstrap_size: int = 1024, min_batch: int = 256,
-                max_batch: int = MAX_BATCH, refine: bool = False,
-                progress_fn=None) -> VamanaGraph:
+                refine: bool = False, progress_fn=None) -> VamanaGraph:
     """Bulk construction: bootstrap + prefix-doubling batch insertion.
 
     Host-side driver (the paper's Fig. 2 pipeline). Batch sizes double as
@@ -257,6 +281,7 @@ def build_graph(vectors: Array, n_total: int, *, params: ConstructionParams,
     from repro.core.vamana import init_graph  # local to avoid cycle
 
     capacity = vectors.shape[0]
+    cap = max_batch(*vectors.shape)
     if n_total > capacity:
         raise ValueError(f"n_total {n_total} exceeds capacity {capacity}")
     graph = init_graph(capacity, params.degree_bound)
@@ -267,7 +292,7 @@ def build_graph(vectors: Array, n_total: int, *, params: ConstructionParams,
     inserted = n0
     while inserted < n_total:
         remaining = n_total - inserted
-        b = min(max(min_batch, 1 << (inserted.bit_length() - 1)), max_batch)
+        b = min(max(min_batch, 1 << (inserted.bit_length() - 1)), cap)
         b = min(b, remaining)
         # round DOWN to a power of two for executable reuse; exact remainder
         # batches only happen once at the tail of each rung
@@ -283,7 +308,7 @@ def build_graph(vectors: Array, n_total: int, *, params: ConstructionParams,
     if refine:  # optional Vamana second pass over everything
         done = 0
         while done < n_total:
-            b = min(max_batch, n_total - done)
+            b = min(cap, n_total - done)
             b = 1 << (b.bit_length() - 1) if b != n_total - done else b
             graph = batch_insert(vectors, graph, jnp.int32(done),
                                  batch_size=b, params=params,

@@ -47,7 +47,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.beam_search import SearchTelemetry
-from repro.core.construction import MAX_BATCH, ConstructionParams
+from repro.core.construction import ConstructionParams, max_batch
 from repro.core.index_core import (
     IndexCore,
     attach_quantizer,
@@ -718,12 +718,13 @@ class ShardedJasperIndex(SearchSurface):
         boot = self._fn("boot", n0=n0)
         self.core = boot(self.core, dealt[:, :n0])
 
-        # prefix-doubling schedule up to MAX_BATCH rows (device memory),
+        # prefix-doubling schedule up to the rung a shard's chip holds,
         # every rung inserted into EVERY shard
+        cap = max_batch(self.cap, data.shape[1])
         inserted = n0
         while inserted < per:
             remaining = per - inserted
-            b = min(max(256, 1 << (inserted.bit_length() - 1)), MAX_BATCH,
+            b = min(max(256, 1 << (inserted.bit_length() - 1)), cap,
                     remaining)
             if b != remaining:
                 b = 1 << (b.bit_length() - 1)

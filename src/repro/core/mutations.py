@@ -299,7 +299,7 @@ def consolidate(vectors: Array, graph: VamanaGraph, state: MutationState, *,
     insertion pipeline against the tombstoned graph (beam search traverses
     THROUGH deleted rows — connectivity — while the live mask keeps them
     out of every pruned edge list), via `batch_insert_at(already_inserted=
-    True)`, in slabs of at most `construction.MAX_BATCH` rows, each slab
+    True)`, in slabs of at most `construction.max_batch` rows, each slab
     against the graph the slabs before it left. Globally good candidates,
     post-churn recall at fresh-build level, and ~2x cheaper than a one-hop
     repair + refine stack.
@@ -329,17 +329,18 @@ def consolidate(vectors: Array, graph: VamanaGraph, state: MutationState, *,
     adj = graph.adjacency
     if refine and touched.size:
         from repro.core.construction import (  # lazy: no cycle
-            MAX_BATCH, batch_insert_at)
-        # re-link in slabs of at most MAX_BATCH rows (one slab over a
+            batch_insert_at, max_batch)
+        slab_rows = max_batch(*vectors.shape)
+        # re-link in slabs of at most slab_rows rows (one slab over a
         # large graph's touched set would not fit device memory). Each slab
         # pads to a power-of-two rung by repeating a real id — a duplicate
         # re-link is idempotent, while -1 padding would corrupt the
         # adjacency scatter; past one slab every slab takes the full rung,
         # so a consolidation compiles one executable
-        rung = (MAX_BATCH if touched.size > MAX_BATCH
+        rung = (slab_rows if touched.size > slab_rows
                 else 1 << max(0, int(touched.size - 1).bit_length()))
-        for s in range(0, touched.size, MAX_BATCH):
-            slab = touched[s:s + MAX_BATCH]
+        for s in range(0, touched.size, slab_rows):
+            slab = touched[s:s + slab_rows]
             t_pad = np.concatenate([slab, np.full((rung - slab.size,),
                                                   slab[0], np.int64)])
             graph = batch_insert_at(vectors, graph,

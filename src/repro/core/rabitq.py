@@ -138,6 +138,13 @@ def rabitq_train(key: Array, vectors: Array, bits: int = 4,
     return RaBitQParams(rotation=rot, centroid=centroid, bits=bits)
 
 
+def _rotate(r: Array, rotation: Array) -> Array:
+    """P r for each row of r, in float32: at default precision a TPU runs
+    the product as one bfloat16 pass, which would round float rows and
+    queries below the float32 the codes are computed for."""
+    return jnp.matmul(r, rotation.T, precision=jax.lax.Precision.HIGHEST)
+
+
 @partial(jax.jit, static_argnames=("bits",))
 def _encode(vectors: Array, rotation: Array, centroid: Array, bits: int) -> RaBitQCodes:
     levels = float(2**bits - 1)
@@ -145,7 +152,7 @@ def _encode(vectors: Array, rotation: Array, centroid: Array, bits: int) -> RaBi
     r = vectors.astype(jnp.float32) - centroid[None, :]
     norm2 = jnp.sum(r * r, axis=-1)                      # |v-c|^2
     norm = jnp.sqrt(norm2)
-    o_un = r @ rotation.T                                # P(v-c)
+    o_un = _rotate(r, rotation)                          # P(v-c)
     o = o_un / jnp.maximum(norm, _EPS)[:, None]          # unit
     delta = 2.0 * jnp.max(jnp.abs(o), axis=-1) / levels  # per-vector step
     delta = jnp.maximum(delta, _EPS)
@@ -175,7 +182,7 @@ def _preprocess_query(queries: Array, rotation: Array, centroid: Array,
                       bits: int) -> RaBitQQuery:
     half = (2**bits - 1) / 2.0
     r = queries.astype(jnp.float32) - centroid[None, :]
-    q_rot = r @ rotation.T
+    q_rot = _rotate(r, rotation)
     return RaBitQQuery(
         q_rot=q_rot,
         query_add=jnp.sum(r * r, axis=-1),

@@ -76,10 +76,17 @@ def test_medoid_masked():
 
 
 # ------------------------------------------------------------------ rabitq
+@pytest.mark.parametrize("dims", [128, 960])
 @pytest.mark.parametrize("bits,max_rel", [(1, 0.8), (4, 0.15), (8, 0.05)])
-def test_rabitq_estimator_quality(bits, max_rel):
-    """Estimator error shrinks with more bits (O(2^-m) per-dim error)."""
-    x, q = randn(300, 128), randn(16, 128)
+def test_rabitq_estimator_quality(bits, max_rel, dims):
+    """Estimator error shrinks with more bits (O(2^-m) per-dim error), at
+    SIFT's width and at GIST's (960)."""
+    if dims == 128:
+        x, q = randn(300, dims), randn(16, dims)
+    else:   # a stream of its own: the draws of the tests below stay put
+        g = np.random.default_rng(dims)
+        x, q = (jnp.asarray(g.normal(size=(n, dims)), jnp.float32)
+                for n in (300, 16))
     params = rabitq_train(jax.random.PRNGKey(0), x, bits=bits)
     codes = rabitq_encode(params, x)
     qq = rabitq_preprocess_query(params, q)

@@ -7,7 +7,9 @@ runs, so these tests pass on a CPU-only machine. What Mosaic refuses
 (slices off the lane tiling, primitives it cannot lower, loads from HBM
 refs) fails here, not on the chip. Each compiled program must hold the
 kernel as a `tpu_custom_call`. The unfused quantized search compiles here
-too, and its frontier merge (`hop.merge`) must hold no gather.
+too, and its frontier merge (`hop.merge`) must hold no gather; so do the
+960-d build and encode programs, whose every remaining matrix product must
+run at `highest` precision.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -25,7 +27,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import rabitq
 from repro.core.beam_search import beam_search_quantized
+from repro.core.construction import (ConstructionParams, batch_insert_at,
+                                     bootstrap_graph)
+from repro.core.medoid import compute_medoid
 from repro.core.rabitq import RaBitQCodes, RaBitQQuery
 from repro.core.vamana import VamanaGraph
 from repro.kernels.distance.ops import gather_l2_chunked, pairwise_l2
@@ -193,3 +199,48 @@ def test_unfused_search_merge_holds_no_gather(spec, d):
                if re.search(r"=\s.*\bgather\(", ln)]
     assert any("hop.score" in ln for ln in gathers)
     assert not any("hop.merge" in ln for ln in gathers)
+
+
+GIST_D, ROWS, BATCH = 960, 4096, 1024
+BUILD = ConstructionParams(degree_bound=R, alpha=1.2, beam_width=L)
+
+
+def _build_programs(spec):
+    """The programs that see float32 rows when a 960-d index is built and
+    encoded, each with the arguments of a small build."""
+    rows, adj = spec((ROWS, GIST_D), jnp.float32), spec((ROWS, R), jnp.int32)
+    one = spec((), jnp.int32)
+    rot, cen = spec((GIST_D, GIST_D), jnp.float32), spec((GIST_D,), jnp.float32)
+    return {
+        "bootstrap_graph": (lambda v, a, n, m: bootstrap_graph(
+            v, VamanaGraph(a, n, m), n0=1024, params=BUILD),
+            (rows, adj, one, one)),
+        "batch_insert_at": (lambda v, a, n, m, ids, sq: batch_insert_at(
+            v, VamanaGraph(a, n, m), ids, params=BUILD, vec_sqnorm=sq),
+            (rows, adj, one, one, spec((BATCH,), jnp.int32),
+             spec((ROWS,), jnp.float32))),
+        "compute_medoid": (compute_medoid,
+                           (rows, spec((ROWS,), jnp.bool_))),
+        "encode": (lambda v, r, c: rabitq._encode(v, r, c, BITS),
+                   (rows, rot, cen)),
+        "preprocess_query": (lambda q, r, c: rabitq._preprocess_query(
+            q, r, c, BITS), (spec((Q, GIST_D), jnp.float32), rot, cen)),
+    }
+
+
+@pytest.mark.parametrize("name", ["bootstrap_graph", "batch_insert_at",
+                                  "compute_medoid", "encode",
+                                  "preprocess_query"])
+def test_float_row_products_run_at_highest_on_the_chip(spec, name):
+    """No product over float32 rows runs as one bfloat16 pass: every
+    `dot` or `convolution` the TPU compiler leaves in a 960-d build or
+    encode program carries `operand_precision={highest,highest}`. The
+    walk's, the prunes' and the medoid's matrix-vector products hold none
+    (the compiler makes them f32 multiply-reduce fusions)."""
+    fn, args = _build_programs(spec)[name]
+    products = [ln for ln in compile_text(fn, *args).splitlines()
+                if re.search(r"=\s*\S+\s+(dot|convolution)\(", ln)]
+    for ln in products:
+        assert "operand_precision={highest,highest}" in ln, ln
+    assert bool(products) == (name in ("bootstrap_graph", "encode",
+                                       "preprocess_query"))
