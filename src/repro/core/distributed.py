@@ -115,8 +115,7 @@ def _core_layout(template: IndexCore, row_axes, wrap):
                         n_free=row1, n_deleted=row1, generation=row1)
     codes = None
     if template.codes is not None:
-        codes = RaBitQCodes(packed=row2, data_add=row1, data_rescale=row1,
-                            bits=template.codes.bits,
+        codes = RaBitQCodes(rows=row2, bits=template.codes.bits,
                             dims=template.codes.dims)
     rq = None
     if template.rq_params is not None:
@@ -495,10 +494,7 @@ class ShardedJasperIndex(SearchSurface):
         c = self.core
         codes = None
         if c.codes is not None:
-            codes = RaBitQCodes(packed=c.codes.packed[rows],
-                                data_add=c.codes.data_add[rows],
-                                data_rescale=c.codes.data_rescale[rows],
-                                bits=c.codes.bits, dims=c.codes.dims)
+            codes = replace(c.codes, rows=c.codes.rows[rows])
         return IndexCore(
             vectors=c.vectors[rows], vec_sqnorm=c.vec_sqnorm[rows],
             adjacency=c.adjacency[rows], n_valid=c.n_valid[s],
@@ -524,12 +520,8 @@ class ShardedJasperIndex(SearchSurface):
 
         codes = None
         if locals_[0].codes is not None:
-            c0 = locals_[0].codes
-            codes = RaBitQCodes(
-                packed=cat(lambda c: c.codes.packed),
-                data_add=cat(lambda c: c.codes.data_add),
-                data_rescale=cat(lambda c: c.codes.data_rescale),
-                bits=c0.bits, dims=c0.dims)
+            codes = replace(locals_[0].codes,
+                            rows=cat(lambda c: c.codes.rows))
         core = IndexCore(
             vectors=cat(lambda c: c.vectors),
             vec_sqnorm=cat(lambda c: c.vec_sqnorm),
@@ -655,12 +647,8 @@ class ShardedJasperIndex(SearchSurface):
         codes = c.codes
         if codes is not None:
             enc = rabitq_encode(c.rq_params, vectors)
-            codes = RaBitQCodes(
-                packed=jnp.where(written[:, None], enc.packed, codes.packed),
-                data_add=jnp.where(written, enc.data_add, codes.data_add),
-                data_rescale=jnp.where(written, enc.data_rescale,
-                                       codes.data_rescale),
-                bits=codes.bits, dims=codes.dims)
+            codes = replace(codes, rows=jnp.where(written[:, None],
+                                                  enc.rows, codes.rows))
         self.core = self._device_put(replace(
             c, vectors=vectors, vec_sqnorm=sqnorm, codes=codes))
 
@@ -929,11 +917,7 @@ class ShardedJasperIndex(SearchSurface):
         c = self.core
         codes = c.codes
         if codes is not None:
-            codes = RaBitQCodes(packed=per_shard_pad(codes.packed, 0),
-                                data_add=per_shard_pad(codes.data_add, 0.0),
-                                data_rescale=per_shard_pad(
-                                    codes.data_rescale, 0.0),
-                                bits=codes.bits, dims=codes.dims)
+            codes = replace(codes, rows=per_shard_pad(codes.rows, 0))
         self.core = self._device_put(replace(
             c,
             vectors=per_shard_pad(c.vectors, 0.0),

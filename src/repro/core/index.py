@@ -316,11 +316,8 @@ class JasperIndex(SearchSurface):
             # is a static slice — the zero tail never hits the rotation)
             enc = rabitq_encode(core.rq_params, vectors[:n])
             c = core.codes
-            core = replace(core, codes=RaBitQCodes(
-                packed=c.packed.at[:n].set(enc.packed),
-                data_add=c.data_add.at[:n].set(enc.data_add),
-                data_rescale=c.data_rescale.at[:n].set(enc.data_rescale),
-                bits=c.bits, dims=c.dims))
+            core = replace(core, codes=replace(
+                c, rows=c.rows.at[:n].set(enc.rows)))
         self.core = core
         if self.pq_codes is not None:
             self.pq_codes = self.pq_codes.at[:n].set(
@@ -652,13 +649,9 @@ class JasperIndex(SearchSurface):
                 packed_bytes_per_vector(self.store_dims, self.bits))
             stats["compression_ratio"] = full / stats["rabitq_bytes_per_row"]
             if self.core.codes is not None:
-                # actual packed bytes resident in HBM (not the formula):
-                # packed codes + the two f32 metadata arrays, capacity rows
-                c = self.core.codes
-                resident = (c.packed.size * c.packed.dtype.itemsize
-                            + c.data_add.size * c.data_add.dtype.itemsize
-                            + c.data_rescale.size
-                            * c.data_rescale.dtype.itemsize)
+                # actual code rows resident in HBM (not the formula):
+                # packed codes + the two f32 factors, capacity rows
+                resident = self.core.codes.rows.nbytes
                 stats["rabitq_resident_bytes"] = float(resident)
                 stats["rabitq_resident_bytes_per_row"] = (
                     resident / self.capacity)

@@ -18,8 +18,8 @@ Layout invariants the sharded layer relies on:
   * `tombstone_bits` packs 8 rows/byte — capacities must be multiples of
     8 so per-shard bitmaps concatenate cleanly (init_core enforces it);
   * `rq_params` (rotation/centroid) is dataset-level state, replicated
-    across shards; `codes` (packed bytes + per-row scalars) is row state,
-    sharded like vectors.
+    across shards; `codes` (one row per vector: packed bytes + the two
+    per-row factors) is row state, sharded like vectors.
 
 All core ops are pure: they take a core (plus host-shaped scalars) and
 return a new core. Host concerns — slot allocation, quantizer training,
@@ -147,11 +147,10 @@ def with_graph(core: IndexCore, graph: VamanaGraph) -> IndexCore:
 def attach_quantizer(core: IndexCore, params: RaBitQParams) -> IndexCore:
     """Install a trained quantizer + capacity-allocated packed buffers."""
     cap = core.capacity
+    # all-zero rows: codes 0 and both factors +0.0
     codes = RaBitQCodes(
-        packed=jnp.zeros((cap, packed_dim(core.store_dims, params.bits)),
-                         jnp.uint8),
-        data_add=jnp.zeros((cap,), jnp.float32),
-        data_rescale=jnp.zeros((cap,), jnp.float32),
+        rows=jnp.zeros((cap, packed_dim(core.store_dims, params.bits) + 8),
+                       jnp.uint8),
         bits=params.bits, dims=core.store_dims)
     return replace(core, codes=codes, rq_params=params)
 
@@ -168,11 +167,7 @@ def core_write_rows(core: IndexCore, ids: Array, rows: Array) -> IndexCore:
     codes = core.codes
     if codes is not None:
         enc = rabitq_encode(core.rq_params, rows)
-        codes = RaBitQCodes(
-            packed=codes.packed.at[ids].set(enc.packed),
-            data_add=codes.data_add.at[ids].set(enc.data_add),
-            data_rescale=codes.data_rescale.at[ids].set(enc.data_rescale),
-            bits=codes.bits, dims=codes.dims)
+        codes = replace(codes, rows=codes.rows.at[ids].set(enc.rows))
     return replace(core, vectors=vectors, vec_sqnorm=sqnorm, codes=codes)
 
 
@@ -428,11 +423,7 @@ def core_grow(core: IndexCore, new_capacity: int) -> IndexCore:
         return core
     codes = core.codes
     if codes is not None:
-        codes = RaBitQCodes(
-            packed=grow_rows(codes.packed, new_capacity, 0),
-            data_add=grow_rows(codes.data_add, new_capacity, 0.0),
-            data_rescale=grow_rows(codes.data_rescale, new_capacity, 0.0),
-            bits=codes.bits, dims=codes.dims)
+        codes = replace(codes, rows=grow_rows(codes.rows, new_capacity, 0))
     return replace(
         core,
         vectors=grow_rows(core.vectors, new_capacity, 0.0),
@@ -553,10 +544,9 @@ def core_from_arrays(data: Mapping, *, bits: int, store_dims: int,
             packed = jnp.asarray(data["rq_packed"])
         else:   # legacy checkpoint with unpacked uint8[N, D] codes
             packed = pack_codes(jnp.asarray(data["rq_codes"]), bits)
-        codes = RaBitQCodes(packed=packed,
-                            data_add=jnp.asarray(data["rq_add"]),
-                            data_rescale=jnp.asarray(data["rq_rescale"]),
-                            bits=bits, dims=store_dims)
+        codes = RaBitQCodes.from_parts(packed, jnp.asarray(data["rq_add"]),
+                                       jnp.asarray(data["rq_rescale"]),
+                                       bits, store_dims)
     return IndexCore(
         vectors=vectors,
         vec_sqnorm=jnp.sum(vectors * vectors, axis=-1),

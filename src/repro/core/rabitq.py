@@ -32,9 +32,11 @@ TPU adaptation (DESIGN.md §2): the GPU implementation exploits sequential
 16-byte loads; on TPU the estimator inner product <codes, q_rot> over a tile
 of candidates IS a matmul (C_tile x D) @ (D x Q_tile) and runs on the MXU —
 see kernels/rabitq_dot. The PACKED form (pack_codes) is the canonical
-device-resident representation: RaBitQCodes stores uint8[N, ceil(D*m/8)]
+device-resident representation: RaBitQCodes stores one uint8 row of
+ceil(D*m/8) code bytes plus the 8 bytes of the two f32 factors per vector
 and nothing wider, so the 8x/4x/2x memory-footprint reduction the paper
-reports is what actually sits in HBM. Consumers either unpack on the fly
+reports is what actually sits in HBM, and a candidate's code and factors
+arrive in one row gather. Consumers either unpack on the fly
 (jnp reference paths) or unpack in-kernel with shift/mask VPU ops (the TPU
 analogue of the paper's in-warp bit arithmetic).
 """
@@ -74,36 +76,83 @@ class RaBitQParams:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=("packed", "data_add", "data_rescale"),
-         meta_fields=("bits", "dims"))
+         data_fields=("rows",), meta_fields=("bits", "dims"))
 @dataclass(frozen=True)
 class RaBitQCodes:
-    """Per-vector quantized storage — packed codes are canonical.
+    """Per-vector quantized storage: one code row per vector.
 
-    packed:       uint8[N, ceil(D*bits/8)]  bit-packed integer codes (the
-                  only full-width device array; see pack_codes for layout)
-    data_add:     f32[N]
-    data_rescale: f32[N]
-    bits / dims:  pytree metadata (static python ints under jit)
+    rows:        uint8[N, P + 8] with P = ceil(D*bits/8): the bit-packed
+                 integer codes in bytes [0, P) (layout: pack_codes), then
+                 data_add and data_rescale as little-endian f32 bit
+                 patterns in bytes [P, P + 4) and [P + 4, P + 8). Scoring
+                 a candidate is one row gather; its factors come with it.
+    bits / dims: pytree metadata (static python ints under jit)
+
+    `packed`, `data_add` and `data_rescale` are read-only views of the rows.
     """
 
-    packed: Array
-    data_add: Array
-    data_rescale: Array
+    rows: Array
     bits: int
     dims: int
+
+    @classmethod
+    def from_parts(cls, packed: Array, data_add: Array, data_rescale: Array,
+                   bits: int, dims: int) -> "RaBitQCodes":
+        """Rows from packed uint8[N, P] codes and the two f32[N] factors."""
+        row = [packed, _f32_bytes(data_add), _f32_bytes(data_rescale)]
+        return cls(rows=jnp.concatenate(row, axis=-1), bits=bits, dims=dims)
+
+    @property
+    def code_width(self) -> int:
+        """P: the packed code bytes at the head of each row."""
+        return packed_dim(self.dims, self.bits)
+
+    @property
+    def packed(self) -> Array:
+        return self.rows[..., :self.code_width]
+
+    @property
+    def data_add(self) -> Array:
+        p = self.code_width
+        return _bytes_f32(self.rows[..., p:p + 4])
+
+    @property
+    def data_rescale(self) -> Array:
+        p = self.code_width
+        return _bytes_f32(self.rows[..., p + 4:p + 8])
 
     def unpacked(self) -> Array:
         """Transient uint8[N, D] view (materialized on demand, never stored)."""
         return unpack_codes(self.packed, self.bits, self.dims)
 
-    def gather_unpacked(self, ids: Array) -> Array:
-        """Gather rows in packed form, then unpack: ids[...] -> uint8[..., D].
+    def gather_packed(self, ids: Array) -> tuple[Array, Array, Array]:
+        """One row gather: ids[...] -> (packed uint8[..., P], data_add
+        f32[...], data_rescale f32[...]). It moves P + 8 bytes per row."""
+        g = self.rows[ids]
+        p = self.code_width
+        return (g[..., :p], _bytes_f32(g[..., p:p + 4]),
+                _bytes_f32(g[..., p + 4:p + 8]))
 
-        The gather moves ceil(D*bits/8) bytes per row — the sequential-load
-        win the paper measures — and the unpack is cheap VPU shift/mask work.
-        """
-        return unpack_codes(self.packed[ids], self.bits, self.dims)
+    def gather(self, ids: Array) -> tuple[Array, Array, Array]:
+        """gather_packed, then unpack the code bytes alone: ids[...] ->
+        (uint8[..., D] codes, data_add, data_rescale). The unpack is cheap
+        VPU shift/mask work and never sees the factor bytes."""
+        packed, add, rescale = self.gather_packed(ids)
+        return unpack_codes(packed, self.bits, self.dims), add, rescale
+
+
+def _f32_bytes(x: Array) -> Array:
+    """f32[...] -> uint8[..., 4]: the little-endian bytes of its bits."""
+    word = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    shifts = jnp.arange(4, dtype=jnp.uint32) * 8
+    return ((word[..., None] >> shifts) & 0xFF).astype(jnp.uint8)
+
+
+def _bytes_f32(b: Array) -> Array:
+    """Inverse of _f32_bytes: uint8[..., 4] -> f32[...], bit for bit."""
+    u = b.astype(jnp.uint32)
+    word = u[..., 0] | (u[..., 1] << 8) | (u[..., 2] << 16) | (u[..., 3] << 24)
+    return jax.lax.bitcast_convert_type(word, jnp.float32)
 
 
 class RaBitQQuery(NamedTuple):
@@ -163,13 +212,8 @@ def _encode(vectors: Array, rotation: Array, centroid: Array, bits: int) -> RaBi
     rescale = jnp.where(norm > _EPS, rescale, 0.0)
     # encode -> pack fused under one jit: the unpacked uint8[N, D] form is a
     # transient value inside this trace, never a resident buffer
-    return RaBitQCodes(
-        packed=pack_codes(u.astype(jnp.uint8), bits),
-        data_add=norm2,
-        data_rescale=rescale,
-        bits=bits,
-        dims=vectors.shape[1],
-    )
+    return RaBitQCodes.from_parts(pack_codes(u.astype(jnp.uint8), bits),
+                                  norm2, rescale, bits, vectors.shape[1])
 
 
 def rabitq_encode(params: RaBitQParams, vectors: Array) -> RaBitQCodes:
@@ -209,11 +253,10 @@ def rabitq_estimate(codes: RaBitQCodes, query: RaBitQQuery,
         rsc = codes.data_rescale[None, :]
     else:
         safe = jnp.maximum(candidate_ids, 0)
-        # gather PACKED rows (the bytes that actually move), unpack after
-        c = codes.gather_unpacked(safe).astype(jnp.float32)         # (Q, K, D)
-        dot = jnp.einsum("qkd,qd->qk", c, query.q_rot)
-        add = codes.data_add[safe]
-        rsc = codes.data_rescale[safe]
+        # one gather of PACKED rows (the bytes that actually move) brings
+        # the codes and both factors; the codes are unpacked after
+        c, add, rsc = codes.gather(safe)
+        dot = jnp.einsum("qkd,qd->qk", c.astype(jnp.float32), query.q_rot)
     est = add + query.query_add[..., None] + rsc * (dot - query.query_sumq[..., None])
     return jnp.maximum(est, 0.0)
 

@@ -213,12 +213,8 @@ def _reshard_cores_impl(cores: list[IndexCore], *, old_id_stride: int,
     all_labels = np.concatenate([np.asarray(c.mut.labels) for c in cores])
     quantized = cores[0].codes is not None
     if quantized:
-        all_packed = np.concatenate([np.asarray(c.codes.packed)
-                                     for c in cores])
-        all_add = np.concatenate([np.asarray(c.codes.data_add)
-                                  for c in cores])
-        all_rescale = np.concatenate([np.asarray(c.codes.data_rescale)
-                                      for c in cores])
+        all_codes = np.concatenate([np.asarray(c.codes.rows)
+                                    for c in cores])
 
     # 2. canonical live sequence -> contiguous balanced groups
     live_flat, old_gids, src_shard = [], [], []
@@ -285,15 +281,8 @@ def _reshard_cores_impl(cores: list[IndexCore], *, old_id_stride: int,
         core = init_core(cap_new, store_dims, degree)
         codes = rq = None
         if quantized:
-            codes = replace(
-                cores[0].codes,
-                packed=jnp.asarray(np.pad(
-                    all_packed[src],
-                    ((0, cap_new - size), (0, 0)))),
-                data_add=jnp.asarray(np.pad(all_add[src],
-                                            (0, cap_new - size))),
-                data_rescale=jnp.asarray(np.pad(all_rescale[src],
-                                                (0, cap_new - size))))
+            codes = replace(cores[0].codes, rows=jnp.asarray(np.pad(
+                all_codes[src], ((0, cap_new - size), (0, 0)))))
             rq = cores[0].rq_params
         medoid = 0
         if size:

@@ -77,10 +77,7 @@ def tier_memory_stats(core, store, *, capacity: int,
     device_rows = rows_full if rows_resident(core) else 0.0
     codes = 0.0
     if core.codes is not None:
-        c = core.codes
-        codes = float(c.packed.size * c.packed.dtype.itemsize
-                      + c.data_add.size * c.data_add.dtype.itemsize
-                      + c.data_rescale.size * c.data_rescale.dtype.itemsize)
+        codes = float(core.codes.rows.nbytes)
     stats = {"rows_tier": store.tier,
              "device_rows_bytes": device_rows,
              "device_codes_bytes": codes,

@@ -121,7 +121,7 @@ def make_rabitq_kernel_scorer(codes: RaBitQCodes, query: RaBitQQuery, *,
     Bulk-gathers candidate code rows in packed form (chunked-load strategy:
     ceil(D*bits/8) + 8 bytes per candidate instead of 4*D), then runs one
     fused unpack + estimator + masking-epilogue kernel per query tile. No
-    re-packing ever happens — codes.packed is the HBM-resident array.
+    re-packing ever happens — codes.rows is the HBM-resident array.
 
     tombstone_bits: optional packed row bitmap (core.mutations) for
     exclude-mode searches — each candidate's bit is gathered alongside its
@@ -131,13 +131,10 @@ def make_rabitq_kernel_scorer(codes: RaBitQCodes, query: RaBitQQuery, *,
     gathered the same way (N_LABEL_BYTES extra bytes per candidate, never
     a dense unpack) and non-matching candidates go dead in the epilogue.
     """
-    packed = codes.packed                            # (N, P) — canonical
-
     def score(ids: Array) -> Array:
         safe = jnp.maximum(ids, 0)
-        cand = packed[safe]                          # (Q, K, P) bulk gather
-        dadd = codes.data_add[safe]
-        drs = codes.data_rescale[safe]
+        # one bulk gather of (Q, K) code rows: packed codes + both factors
+        cand, dadd, drs = codes.gather_packed(safe)
         live = None
         if tombstone_bits is not None:
             from repro.core.mutations import bitmap_gather

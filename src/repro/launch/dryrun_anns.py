@@ -76,8 +76,7 @@ def abstract_core(n_shards: int, cap: int, dims: int, *,
     codes = rq = None
     if quantized:
         p_dim = (dims * bits + 7) // 8
-        codes = RaBitQCodes(packed=st((rows, p_dim), jnp.uint8),
-                            data_add=st((rows,)), data_rescale=st((rows,)),
+        codes = RaBitQCodes(rows=st((rows, p_dim + 8), jnp.uint8),
                             bits=bits, dims=dims)
         rq = RaBitQParams(rotation=st((dims, dims)), centroid=st((dims,)),
                           bits=bits)

@@ -24,7 +24,9 @@ from repro.core import (
     rabitq_preprocess_query,
     rabitq_train,
 )
-from repro.core.beam_search import make_exact_scorer
+from repro.core.beam_search import (beam_search_quantized, make_exact_scorer)
+from repro.core.rabitq import (RaBitQCodes, pack_codes, packed_dim,
+                               unpack_codes)
 from repro.core.construction import ConstructionParams, build_graph
 from repro.core.vamana import graph_degree_stats, init_graph, validate_graph
 
@@ -121,6 +123,87 @@ def test_rabitq_zero_vector():
     qq = rabitq_preprocess_query(params, q)
     est = rabitq_estimate(codes, qq)
     assert bool(jnp.isfinite(est).all())
+
+
+# --------------------------------------------------------------- code rows
+# RaBitQCodes holds one uint8[N, P + 8] row per vector: the packed codes,
+# then data_add and data_rescale as f32 bit patterns. The reference is the
+# three arrays held apart; these tests hold the rows to it bit for bit.
+CODE_ROW_CASES = [(b, d) for b in (1, 2, 4, 8) for d in (96, 128, 960)]
+_FI = np.finfo(np.float32)
+SPECIAL_FACTORS = np.array([0.0, -0.0, _FI.max, -_FI.max, _FI.tiny,
+                            -_FI.tiny], np.float32)
+
+
+def _three_arrays(codes):
+    """Host copies of the three arrays (packed, add, rescale) of the rows."""
+    return (np.asarray(codes.packed), np.asarray(codes.data_add),
+            np.asarray(codes.data_rescale))
+
+
+def _three_array_estimate(packed, add, rescale, query, ids, bits, dims):
+    """The estimator over three separate arrays: a row gather of the packed
+    codes and two element gathers of the factors."""
+    safe = jnp.maximum(ids, 0)
+    c = unpack_codes(packed[safe], bits, dims).astype(jnp.float32)
+    dot = jnp.einsum("qkd,qd->qk", c, query.q_rot)
+    est = (add[safe] + query.query_add[..., None]
+           + rescale[safe] * (dot - query.query_sumq[..., None]))
+    return jnp.maximum(est, 0.0)
+
+
+def _bits32(x):
+    return np.asarray(x).view(np.uint32)
+
+
+@pytest.mark.parametrize("bits,dims", CODE_ROW_CASES)
+def test_code_rows_views_roundtrip(bits, dims):
+    """from_parts, then the views, give back packed, data_add and
+    data_rescale bit for bit, signed zeros and the extreme normals too."""
+    g = np.random.default_rng(bits * 1000 + dims)
+    n = 40
+    packed = np.asarray(pack_codes(
+        jnp.asarray(g.integers(0, 2 ** bits, (n, dims), dtype=np.uint8)),
+        bits))
+    add = (g.normal(size=n) * 300).astype(np.float32)
+    rescale = g.normal(size=n).astype(np.float32)
+    add[:6] = SPECIAL_FACTORS
+    rescale[-6:] = SPECIAL_FACTORS
+    c = RaBitQCodes.from_parts(jnp.asarray(packed), jnp.asarray(add),
+                               jnp.asarray(rescale), bits, dims)
+    assert c.rows.dtype == jnp.uint8
+    assert c.rows.shape == (n, packed_dim(dims, bits) + 8)
+    got = _three_arrays(c)
+    assert np.array_equal(got[0], packed)
+    assert np.array_equal(_bits32(got[1]), _bits32(add))
+    assert np.array_equal(_bits32(got[2]), _bits32(rescale))
+    again = RaBitQCodes.from_parts(c.packed, c.data_add, c.data_rescale,
+                                   bits, dims)
+    assert np.array_equal(np.asarray(again.rows), np.asarray(c.rows))
+
+
+@pytest.mark.parametrize("bits,dims", CODE_ROW_CASES)
+def test_code_rows_estimate_matches_three_arrays(bits, dims):
+    """rabitq_estimate over candidate ids (some -1) reads the rows' one
+    gather and equals the three-array estimator bit for bit."""
+    g = np.random.default_rng(dims * 10 + bits)
+    x = jnp.asarray(g.normal(size=(300, dims)), jnp.float32)
+    params = rabitq_train(jax.random.PRNGKey(bits), x, bits=bits)
+    packed, add, rescale = _three_arrays(rabitq_encode(params, x))
+    add, rescale = add.copy(), rescale.copy()
+    add[:2], rescale[:2] = SPECIAL_FACTORS[:2], SPECIAL_FACTORS[4:6]
+    codes = RaBitQCodes.from_parts(jnp.asarray(packed), jnp.asarray(add),
+                                   jnp.asarray(rescale), bits, dims)
+    query = rabitq_preprocess_query(
+        params, jnp.asarray(g.normal(size=(12, dims)), jnp.float32))
+    ids = g.integers(-1, 300, (12, 40)).astype(np.int32)
+    ids[:, 0], ids[:, 1] = -1, 1
+    ids = jnp.asarray(ids)
+    got = jax.jit(rabitq_estimate)(codes, query, ids)
+    want = jax.jit(_three_array_estimate, static_argnums=(5, 6))(
+        jnp.asarray(packed), jnp.asarray(add), jnp.asarray(rescale), query,
+        ids, bits, dims)
+    assert np.array_equal(_bits32(got), _bits32(want))
 
 
 # ---------------------------------------------------------------------- pq
@@ -303,13 +386,70 @@ def test_rabitq_codes_packed_resident(built_index):
     c = idx.rabitq_codes
     assert c.packed.shape == (idx.capacity, packed_dim(idx.store_dims, 4))
     assert c.packed.dtype == jnp.uint8
-    # the dataclass holds no unpacked uint8[N, D] buffer
-    assert set(type(c).__dataclass_fields__) == {
-        "packed", "data_add", "data_rescale", "bits", "dims"}
+    # the dataclass holds one row table and no unpacked uint8[N, D] buffer
+    assert set(type(c).__dataclass_fields__) == {"rows", "bits", "dims"}
+    assert c.rows.shape == (idx.capacity, c.packed.shape[1] + 8)
+    assert c.rows.dtype == jnp.uint8
     stats = idx.memory_stats()
     expected = (c.packed.shape[0] * c.packed.shape[1]   # packed codes
                 + 2 * 4 * idx.capacity)                 # two f32 metadata
     assert stats["rabitq_resident_bytes"] == expected
+
+
+@pytest.mark.parametrize("beam_width,expand", [(16, 1), (32, 1), (32, 4)])
+def test_code_rows_search_bit_identical(built_index, beam_width, expand):
+    """The unfused quantized search over the rows returns the ids, the
+    distance bits and the hop counts of the search over three arrays."""
+    idx, _ = built_index
+    codes = idx.rabitq_codes
+    packed, add, rescale = (jnp.asarray(a) for a in _three_arrays(codes))
+    # a stream of its own: the module RNG's draws of later tests stay put
+    q = np.random.default_rng(beam_width * 10 + expand).normal(size=(24, 48))
+    query = rabitq_preprocess_query(idx.rabitq_params,
+                                    jnp.asarray(q, jnp.float32))
+
+    def three_array_score(ids):
+        return _three_array_estimate(packed, add, rescale, query, ids,
+                                codes.bits, codes.dims)
+
+    kw = dict(beam_width=beam_width, max_iters=2 * beam_width + 12,
+              expand_per_iter=expand)
+    new = beam_search_quantized(idx.graph, codes, query, **kw)
+    old = beam_search(idx.graph, three_array_score, 24, **kw)
+    assert np.array_equal(np.asarray(new.frontier_ids),
+                          np.asarray(old.frontier_ids))
+    assert np.array_equal(_bits32(new.frontier_dists),
+                          _bits32(old.frontier_dists))
+    assert np.array_equal(np.asarray(new.n_hops), np.asarray(old.n_hops))
+
+
+def test_checkpoint_three_arrays_load_into_code_rows(tmp_path, built_index):
+    """A checkpoint keeps the keys rq_packed, rq_add and rq_rescale: three
+    arrays written apart (as a checkpoint of the three-array form holds
+    them) load into the rows bit for bit, and saving again writes the
+    same arrays under the same keys."""
+    idx, _ = built_index
+    first = str(tmp_path / "first.npz")
+    idx.save(first)
+    with np.load(first) as z:
+        arrays = {k: z[k] for k in z.files}
+    assert {"rq_packed", "rq_add", "rq_rescale"} <= set(arrays)
+    assert not any("row" in k for k in arrays if k.startswith("rq_"))
+    arrays["rq_add"][:6] = SPECIAL_FACTORS
+    arrays["rq_rescale"][-6:] = SPECIAL_FACTORS
+    np.savez(first, **arrays)
+    idx2 = JasperIndex.load(first)
+    packed, add, rescale = _three_arrays(idx2.rabitq_codes)
+    assert np.array_equal(packed, arrays["rq_packed"])
+    assert np.array_equal(_bits32(add), _bits32(arrays["rq_add"]))
+    assert np.array_equal(_bits32(rescale), _bits32(arrays["rq_rescale"]))
+    second = str(tmp_path / "second.npz")
+    idx2.save(second)
+    with np.load(second) as z:
+        assert sorted(z.files) == sorted(arrays)
+        for k in z.files:
+            assert z[k].dtype == arrays[k].dtype, k
+            assert z[k].tobytes() == arrays[k].tobytes(), k
 
 
 def test_rabitq_kernel_search_matches_jnp(built_index):
@@ -480,17 +620,34 @@ def test_insert_after_delete_reuses_slots(churn_index):
 
 
 def test_grow_preserves_packed_codes(churn_index):
+    """Grow copies every code row whole (codes and factor bytes) and zero-
+    fills the new rows."""
     idx, _, queries, _ = churn_index
-    packed = np.asarray(idx.rabitq_codes.packed)
+    rows = np.asarray(idx.rabitq_codes.rows)
     adj = np.asarray(idx.graph.adjacency)
     i1, d1 = idx.search_rabitq(queries, 10, beam_width=32)
     idx.grow()
     assert idx.capacity == 1800
-    assert (np.asarray(idx.rabitq_codes.packed)[:900] == packed).all()
+    assert (np.asarray(idx.rabitq_codes.rows)[:900] == rows).all()
+    assert (np.asarray(idx.rabitq_codes.rows)[900:] == 0).all()
     assert (np.asarray(idx.graph.adjacency)[:900] == adj).all()
     assert (np.asarray(idx.graph.adjacency)[900:] == -1).all()
     i2, d2 = idx.search_rabitq(queries, 10, beam_width=32)
     assert (np.asarray(i1) == np.asarray(i2)).all()
+
+
+def test_insert_writes_whole_code_rows(churn_index):
+    """An insert writes each new row's codes and factor bytes as the encoder
+    gives them and leaves every other row's bytes as they were."""
+    idx, _, _, rng = churn_index
+    before = np.asarray(idx.rabitq_codes.rows)
+    new = rng.normal(size=(50, 32)).astype(np.float32)
+    got = np.asarray(idx.insert(new))
+    after = np.asarray(idx.rabitq_codes.rows)
+    enc = rabitq_encode(idx.rabitq_params, jnp.asarray(new))
+    assert np.array_equal(after[got], np.asarray(enc.rows))
+    rest = np.setdiff1d(np.arange(idx.capacity), got)
+    assert np.array_equal(after[rest], before[rest])
 
 
 def test_insert_auto_grows(churn_index):

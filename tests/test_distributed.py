@@ -207,14 +207,15 @@ def test_sharded_grow_and_single_device_parity():
                                 quantized=True)
         assert abs(r_sh - r_solo_eq) < 0.05, (r_sh, r_solo_eq)
 
-        # grow: copy-extension only — packed codes per shard bit-identical
-        # and GLOBAL ids stable (id encoding is stride-, not cap-, based)
+        # grow: copy-extension only — code rows (codes and factor bytes)
+        # per shard bit-identical and GLOBAL ids stable (id encoding is
+        # stride-, not cap-, based)
         ids_pre, _ = sh.search(queries[:16], k=10, beam_width=32,
                                quantized=True)
-        packed0 = np.asarray(sh.core.codes.packed).reshape(4, CAP, -1)
+        packed0 = np.asarray(sh.core.codes.rows).reshape(4, CAP, -1)
         adj0 = np.asarray(sh.core.adjacency).reshape(4, CAP, -1)
         sh.grow(2 * CAP)
-        packed1 = np.asarray(sh.core.codes.packed).reshape(4, 2 * CAP, -1)
+        packed1 = np.asarray(sh.core.codes.rows).reshape(4, 2 * CAP, -1)
         adj1 = np.asarray(sh.core.adjacency).reshape(4, 2 * CAP, -1)
         assert (packed1[:, :CAP] == packed0).all()
         assert (packed1[:, CAP:] == 0).all()
@@ -233,10 +234,10 @@ def test_sharded_grow_and_single_device_parity():
 
 def test_sharded_reshard_restore():
     """Elastic resharding: a checkpoint saved at 4 shards restores at 2
-    and 8; live rows survive (packed codes bit-identical through the
-    translation), dead ids translate to -1, recall at equal total budget
-    stays within tolerance, and the fused kernel path leaks no
-    tombstones after the move."""
+    and 8; live rows survive (code rows, codes and factor bytes,
+    bit-identical through the translation), dead ids translate to -1,
+    recall at equal total budget stays within tolerance, and the fused
+    kernel path leaks no tombstones after the move."""
     out = run_with_devices("""
         import tempfile, os, numpy as np, jax
         from repro.core.distributed import ShardedJasperIndex
@@ -258,7 +259,7 @@ def test_sharded_reshard_restore():
         d = tempfile.mkdtemp(); path = os.path.join(d, "ck")
         idx.save(path)
         r_base = idx.recall(queries, 10, beam_width=64, quantized=True)
-        packed4 = np.asarray(idx.core.codes.packed).reshape(4, 1024, -1)
+        packed4 = np.asarray(idx.core.codes.rows).reshape(4, 1024, -1)
 
         for shards, mesh in [(2, make_mesh((2, 4), ("data", "model"))),
                              (8, make_mesh((8,), ("data",)))]:
@@ -273,8 +274,9 @@ def test_sharded_reshard_restore():
             mapped = tr.apply(tr.old_ids)
             assert (mapped >= 0).all()
             assert np.unique(mapped).size == mapped.size
-            # packed codes of moved rows are bit-identical (no re-encode)
-            new_packed = np.asarray(idx2.core.codes.packed).reshape(
+            # code rows of moved rows (codes and factor bytes) are
+            # bit-identical (no re-encode)
+            new_packed = np.asarray(idx2.core.codes.rows).reshape(
                 shards, idx2.cap, -1)
             probe = tr.old_ids[:: max(1, len(tr) // 64)]
             for og, ng in zip(probe, tr.apply(probe)):

@@ -7,9 +7,10 @@ runs, so these tests pass on a CPU-only machine. What Mosaic refuses
 (slices off the lane tiling, primitives it cannot lower, loads from HBM
 refs) fails here, not on the chip. Each compiled program must hold the
 kernel as a `tpu_custom_call`. The unfused quantized search compiles here
-too, and its frontier merge (`hop.merge`) must hold no gather; so do the
-960-d build and encode programs, whose every remaining matrix product must
-run at `highest` precision.
+too: its frontier merge (`hop.merge`) must hold no gather, and its scoring
+(`hop.score`) one, of whole code rows. So do the 960-d build and encode
+programs, whose every remaining matrix product must run at `highest`
+precision.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -89,8 +90,8 @@ def test_fused_search_compiles(spec, d, quantized, mode, masked):
     tombstone gather, the label filter and the telemetry counters."""
     p = packed_width(d)
 
-    def run(adj, nv, med, vec, sqn, qs, packed, dadd, drs, qrot, qadd, qsum,
-            tomb, lab, fb):
+    def run(adj, nv, med, vec, sqn, qs, rows, qrot, qadd, qsum, tomb, lab,
+            fb):
         kw = dict(mode=mode, beam_width=L, max_iters=96, telemetry=masked,
                   interpret=False)
         if masked:
@@ -99,7 +100,7 @@ def test_fused_search_compiles(spec, d, quantized, mode, masked):
         g = VamanaGraph(adj, nv, med)
         if quantized:
             r = fused_beam_search(
-                g, codes=RaBitQCodes(packed, dadd, drs, BITS, d),
+                g, codes=RaBitQCodes(rows, BITS, d),
                 rq_query=RaBitQQuery(qrot, qadd, qsum), **kw)
         else:
             r = fused_beam_search(g, queries=qs, vectors=vec,
@@ -109,8 +110,7 @@ def test_fused_search_compiles(spec, d, quantized, mode, masked):
     args = (spec((CAP, R), jnp.int32), spec((), jnp.int32),
             spec((), jnp.int32), spec((CAP, d), jnp.float32),
             spec((CAP,), jnp.float32), spec((Q, d), jnp.float32),
-            spec((CAP, p), jnp.uint8), spec((CAP,), jnp.float32),
-            spec((CAP,), jnp.float32), spec((Q, d), jnp.float32),
+            spec((CAP, p + 8), jnp.uint8), spec((Q, d), jnp.float32),
             spec((Q,), jnp.float32), spec((Q,), jnp.float32),
             spec((CAP // 8,), jnp.uint8), spec((CAP, 4), jnp.uint8),
             spec((4,), jnp.int32))
@@ -178,27 +178,63 @@ def test_topk_compiles(spec, k):
     assert "tpu_custom_call" in compile_text(run, *args)
 
 
+@pytest.fixture(scope="module")
+def unfused_search_hlo(spec):
+    """The default lane (jnp quantized, unfused) compiled over a table of
+    code rows uint8[CAP, P + 8]: d -> optimized HLO, one compile per width."""
+    compiled = {}
+
+    def hlo(d: int) -> str:
+        if d not in compiled:
+            def run(adj, nv, med, rows, qrot, qadd, qsum):
+                r = beam_search_quantized(
+                    VamanaGraph(adj, nv, med), RaBitQCodes(rows, BITS, d),
+                    RaBitQQuery(qrot, qadd, qsum), beam_width=L,
+                    max_iters=96)
+                return r.frontier_ids, r.frontier_dists, r.n_hops
+
+            args = (spec((CAP, R), jnp.int32), spec((), jnp.int32),
+                    spec((), jnp.int32),
+                    spec((CAP, packed_width(d) + 8), jnp.uint8),
+                    spec((Q, d), jnp.float32), spec((Q,), jnp.float32),
+                    spec((Q,), jnp.float32))
+            compiled[d] = compile_text(run, *args)
+        return compiled[d]
+    return hlo
+
+
+def gathers_with_operand(hlo: str) -> list[tuple[str, str]]:
+    """(gather line, type of its first operand) for every gather."""
+    types = dict(re.findall(r"(%[\w.-]+) = (\S+?)\{", hlo))
+    out = []
+    for ln in hlo.splitlines():
+        m = re.search(r"=\s.*\bgather\((%[\w.-]+),", ln)
+        if m:
+            out.append((ln, types.get(m.group(1), "")))
+    return out
+
+
 @pytest.mark.parametrize("d", DIMS)
-def test_unfused_search_merge_holds_no_gather(spec, d):
+def test_unfused_search_merge_holds_no_gather(unfused_search_hlo, d):
     """The default lane (jnp quantized, unfused): the hop's top-L merge
     carries ids and visited bits through its sort, so no gather in the
     compiled program carries `hop.merge` in its op_name; the code gathers
     of `hop.score` do."""
-    def run(adj, nv, med, packed, dadd, drs, qrot, qadd, qsum):
-        r = beam_search_quantized(
-            VamanaGraph(adj, nv, med), RaBitQCodes(packed, dadd, drs, BITS, d),
-            RaBitQQuery(qrot, qadd, qsum), beam_width=L, max_iters=96)
-        return r.frontier_ids, r.frontier_dists, r.n_hops
-
-    args = (spec((CAP, R), jnp.int32), spec((), jnp.int32),
-            spec((), jnp.int32), spec((CAP, packed_width(d)), jnp.uint8),
-            spec((CAP,), jnp.float32), spec((CAP,), jnp.float32),
-            spec((Q, d), jnp.float32), spec((Q,), jnp.float32),
-            spec((Q,), jnp.float32))
-    gathers = [ln for ln in compile_text(run, *args).splitlines()
-               if re.search(r"=\s.*\bgather\(", ln)]
+    gathers = [ln for ln, _ in gathers_with_operand(unfused_search_hlo(d))]
     assert any("hop.score" in ln for ln in gathers)
     assert not any("hop.merge" in ln for ln in gathers)
+
+
+@pytest.mark.parametrize("d", DIMS + (960,))
+def test_unfused_search_scores_with_one_row_gather(unfused_search_hlo, d):
+    """`hop.score` of the default lane holds exactly one gather, of whole
+    code rows from the 2-D uint8[CAP, P + 8] table: the RaBitQ factors come
+    in the row, and no gather in the program reads a rank-1 f32 array."""
+    gathers = gathers_with_operand(unfused_search_hlo(d))
+    score = [(ln, t) for ln, t in gathers if "hop.score" in ln]
+    assert len(score) == 1, score
+    assert score[0][1] == f"u8[{CAP},{packed_width(d) + 8}]", score
+    assert not [t for _, t in gathers if re.fullmatch(r"f32\[\d+\]", t)]
 
 
 GIST_D, ROWS, BATCH = 960, 4096, 1024
